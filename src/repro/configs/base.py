@@ -2,7 +2,7 @@
 
 One ``ModelConfig`` describes any of the 10 assigned architectures (plus
 the paper's own GPT-2/Llama configs). Block shapes for BLaST are derived
-per-arch so blocks tile the *per-TP-shard* weight (DESIGN.md §6).
+per-arch so blocks tile the weight shard one chip serves (``with_blast``).
 """
 from __future__ import annotations
 
@@ -120,10 +120,9 @@ class ParallelConfig:
 def derive_block_shape(d_in: int, d_out: int, tp: int,
                        shard_out: bool = True) -> tuple[int, int]:
     """Largest (b_in, b_out) in {128,64,32,16,8} tiling the per-shard
-    weight (DESIGN.md §6). ``shard_out``: the out dim is TP-sharded
-    (W1/W2); otherwise the in dim is (W3). We use ONE block shape per
-    model, so take the constraint over the sharded d_ff and the
-    replicated d_model."""
+    weight. ``shard_out``: the out dim is TP-sharded (W1/W2); otherwise
+    the in dim is (W3). We use ONE block shape per model, so take the
+    constraint over the sharded d_ff and the replicated d_model."""
     def largest(dim: int) -> int:
         for b in (128, 64, 32, 16, 8):
             if dim % b == 0:
@@ -133,17 +132,22 @@ def derive_block_shape(d_in: int, d_out: int, tp: int,
     return largest(d_in), largest(local_out)
 
 
-def with_blast(cfg: ModelConfig, tp: int = 16, **overrides) -> ModelConfig:
+def with_blast(cfg: ModelConfig, tp: int = 1, **overrides) -> ModelConfig:
     """Attach a BlastSpec with per-arch derived block shape.
 
-    For MoE archs the experts are EP-sharded (not intra-expert), so the
-    expert d_ff is NOT divided by tp when deriving the block shape."""
+    ``tp`` is the tensor-parallel width of the shard one chip serves:
+    1 (the whole d_ff) unless the model is served split across chips.
+    The Pallas kernels' blocks need a 128-wide (or whole-array) output
+    tile on the chip, so a narrower b_out forced by a large ``tp`` makes
+    them uncompilable. For MoE archs the experts are EP-sharded (not
+    intra-expert), so the expert d_ff is NOT divided by tp. The rest of
+    ``cfg.blast`` (schedule, sparsity) is kept."""
     ff = cfg.moe_d_ff if cfg.is_moe else cfg.d_ff
     shard_out = not cfg.is_moe
     b_in, b_out = derive_block_shape(cfg.d_model, ff, tp,
                                      shard_out=shard_out)
-    spec = dataclasses.replace(
-        BlastSpec(enabled=True, b_in=b_in, b_out=b_out), **overrides)
+    spec = dataclasses.replace(cfg.blast, enabled=True, b_in=b_in,
+                               b_out=b_out, **overrides)
     return dataclasses.replace(cfg, blast=spec)
 
 

@@ -1,8 +1,9 @@
 """stablelm-3b [dense] — 32L d_model=2560 32H (MHA kv=32) d_ff=6912,
 vocab=50304. [hf:stabilityai/stablelm-*]
 
-d_ff/TP = 432 forces b_out=16 at TP=16 (DESIGN.md §6); the padded-d_ff
-variant re-enabling 128-wide blocks is a §Perf lever."""
+Served whole on one chip (tp=1): d_ff = 6912 = 54 x 128, so BLaST blocks
+are (128, 128). A 16-way tensor-parallel shard (d_ff/16 = 432) would
+force b_out = 16, which the chip's Pallas tiling refuses."""
 from repro.configs.base import ModelConfig, reduced, with_blast
 
 CONFIG = with_blast(ModelConfig(

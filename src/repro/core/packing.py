@@ -19,6 +19,7 @@ scatter) — but serving treats packed weights as constants.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -116,18 +117,16 @@ def unpack(p: PackedBCSC) -> jax.Array:
         p.kb * b_in, nb * b_out)
 
 
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def pack_stacked(w: jax.Array, block_mask: jax.Array, b_in: int, b_out: int,
                  nnz: int) -> PackedBCSC:
-    """vmap ``pack`` over arbitrary leading dims (layers, experts)."""
-    lead = w.shape[:-2]
-    if not lead:
-        return pack(w, block_mask, b_in, b_out, nnz)
-    fn = lambda wi, mi: pack(wi, mi, b_in, b_out, nnz)
-    for _ in lead:
-        fn = jax.vmap(fn)
-    p = fn(w, block_mask)
-    return PackedBCSC(blocks=p.blocks, idx=p.idx,
-                      kb=w.shape[-2] // b_in)
+    """``pack`` over arbitrary leading dims (layers, experts), one matrix
+    at a time (``lax.map``): the pack's transposed copy and gather then
+    hold one layer's weight, not the whole stack's."""
+    fn = lambda wm: pack(*wm, b_in, b_out, nnz)
+    for _ in w.shape[:-2]:
+        fn = functools.partial(jax.lax.map, fn)
+    return fn((w, block_mask))
 
 
 def pad_nnz(p: PackedBCSC, nnz: int) -> PackedBCSC:

@@ -8,33 +8,6 @@ import dataclasses
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax >= 0.6 exposes jax.shard_map
-    _shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-import inspect as _inspect
-
-# partial-manual shard_map (manual over a subset of mesh axes via
-# ``axis_names``) only works on newer jax; the 0.4.x ``auto=`` spelling
-# crashes XLA with "Check failed: sharding.IsManualSubgroup()" — gate
-# the deferred-reduction train step on this.
-HAS_PARTIAL_MANUAL = \
-    "axis_names" in _inspect.signature(_shard_map).parameters
-
-if "check_vma" in _inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    # older jax: check_vma is called check_rep, and partial-manual mode
-    # takes the AUTO axis set instead of the manual ``axis_names``
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        if "axis_names" in kw:
-            manual = frozenset(kw.pop("axis_names"))
-            kw["auto"] = frozenset(kw["mesh"].axis_names) - manual
-        return _shard_map(f, **kw)
-
 
 @dataclasses.dataclass(frozen=True)
 class DistContext:

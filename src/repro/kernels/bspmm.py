@@ -17,7 +17,10 @@ BCSC*: every block-column holds exactly ``nnz`` kept (b_in, b_out) blocks
     nnz step; MXU engaged via jnp.dot with preferred f32 accumulation.
 
 Validated in interpret mode against ``ref.py`` over shape/dtype sweeps
-(tests/test_kernels_bspmm.py).
+(tests/test_kernels_bspmm.py) and compiled for a TPU v5e at stablelm-3b
+widths (tests/test_tpu_compile.py). The X and output tiles
+``(blk_m, b_in)`` / ``(blk_m, b_out)`` must be lane-wide on the chip:
+b_in and b_out multiples of 128 (or the whole K and N).
 """
 from __future__ import annotations
 
@@ -29,9 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PackedBCSC
-
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 def _bspmm_kernel(idx_ref, x_ref, w_ref, o_ref, acc_ref):
@@ -79,16 +79,13 @@ def bspmm(x: jax.Array, packed: PackedBCSC, *, blk_m: int = 128,
                                lambda i, j, k, idx: (i, j)),
         scratch_shapes=[pltpu.VMEM((blk_m, b_out), jnp.float32)],
     )
-    kwargs = {}
-    if _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         _bspmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(packed.idx, x, packed.blocks)
 
 
@@ -176,17 +173,14 @@ def _fused_glu_joint(x, p_gate, p_up, *, act, blk_m, interpret):
         scratch_shapes=[pltpu.VMEM((blk_m, b_out), jnp.float32),
                         pltpu.VMEM((blk_m, b_out), jnp.float32)],
     )
-    kwargs = {}
-    if _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     kernel = functools.partial(_fused_glu_joint_kernel, _ACT_IDS[act])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nb * b_out), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(p_gate.idx, x, p_gate.blocks, p_up.blocks)
 
 
@@ -234,15 +228,12 @@ def fused_glu(x: jax.Array, p_gate: PackedBCSC, p_up: PackedBCSC, *,
         scratch_shapes=[pltpu.VMEM((blk_m, b_out), jnp.float32),
                         pltpu.VMEM((blk_m, b_out), jnp.float32)],
     )
-    kwargs = {}
-    if _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     kernel = functools.partial(_fused_glu_kernel, _ACT_IDS[act])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nb * b_out), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(p_gate.idx, p_up.idx, x, x, p_gate.blocks, p_up.blocks)

@@ -24,9 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PackedBCSC
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def first_visit_flags(idx: np.ndarray, kb: int) -> np.ndarray:
     """(Nb, nnz) int32: 1 where this (j,k) is the first occurrence of
@@ -80,16 +77,13 @@ def _bspmm_t_call(dy, blocks, idx, first, kb, *, blk_m=128,
         out_specs=pl.BlockSpec((blk_m, b_in),
                                lambda i, j, k, idx, first: (i, idx[j, k])),
     )
-    kwargs = {}
-    if _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     return pl.pallas_call(
         _bspmm_t_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, kb * b_in), dy.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(idx, first, dy, blocks)
 
 

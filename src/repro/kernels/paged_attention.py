@@ -11,15 +11,24 @@ into a flash-decode online-softmax accumulation — no gathered
 intermediate ever exists (the paper's "only necessary blocks are
 loaded", applied to the KV cache instead of the weights).
 
-grid = (lanes, kv heads, pages); the page axis is ``arbitrary`` (it
-carries the running max / sum / accumulator scratch), lanes and heads
-are parallel. Masking (causal, window, ragged left-pad) arrives as an
-additive-bias row per (lane, slot) — precomputed in XLA from the same
+grid = (lanes, pages); the page axis is ``arbitrary`` (it carries the
+running max / sum / accumulator scratch), lanes are parallel. One grid
+step DMAs one whole pool page across ALL kv heads — the K/V block
+``(1, ps, KV, hd)`` keeps the pool's last two dims whole, which is what
+the TPU's (8, 128) tiling rule accepts for any KV; cutting one head out
+(a ``(1, ps, 1, hd)`` block) is refused whenever KV > 1. Every head of
+the page is then folded in-kernel on the vector unit: scores are a
+broadcast multiply and a lane reduction over ``hd``, the value update a
+reduction over the page's ``ps`` rows, so the pool layout (and with it
+``transformer.init_paged_cache``, offload and recovery) stays as it is.
+Masking (causal, window, ragged left-pad) arrives as an additive-bias
+row per (lane, slot) — precomputed in XLA from the same
 ``_cache_positions`` logic as the dense path, so the two paths mask
 identically.
 
 Validated in interpret mode against the XLA gather path
-(tests/test_paged_kv.py); the engine picks it via
+(tests/test_paged_kv.py) and compiled for a TPU v5e at stablelm-3b
+widths (tests/test_tpu_compile.py); the engine picks it via
 ``attn_backend='pallas'``.
 
 Mixed read-page buckets per lane: the grid reads the SAME ``R`` pages
@@ -46,16 +55,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _paged_decode_kernel(scale, softcap, bt_ref, q_ref, k_ref, v_ref,
                          bias_ref, o_ref, acc_ref, m_ref, l_ref):
-    """One (lane b, kv head h, page j) grid step: fold pool page
-    bt[b, j] into lane b's online softmax for head h."""
-    j = pl.program_id(2)
-    npg = pl.num_programs(2)
+    """One (lane b, page j) grid step: fold pool page bt[b, j] into lane
+    b's online softmax for every head. Per query group g the running
+    state is kept per kv head: m/l (KV, 1), acc (KV, hd)."""
+    j = pl.program_id(1)
+    npg = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -63,30 +70,30 @@ def _paged_decode_kernel(scale, softcap, bt_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]                                  # (G, hd)
-    k = k_ref[0, :, 0, :]                            # (ps, hd)
-    v = v_ref[0, :, 0, :]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    bias = bias_ref[0]                               # (ps,) 0 / NEG_INF
-    valid = bias > NEG_INF / 2
-    s = jnp.where(valid[None, :], s, NEG_INF)        # (G, ps)
+    k = k_ref[0].astype(jnp.float32)                 # (ps, KV, hd)
+    v = v_ref[0]
+    valid = bias_ref[0, 0] > NEG_INF / 2             # (ps, 1, 1)
+    for g in range(q_ref.shape[1]):
+        q = q_ref[0, g].astype(jnp.float32)          # (KV, hd)
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(valid, s, NEG_INF)             # (ps, KV, 1)
 
-    m_prev = m_ref[...]                              # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(valid[None, :], p, 0.0)            # fully-masked pages
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[g]                            # (KV, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[None])
+        p = jnp.where(valid, p, 0.0)                 # fully-masked pages
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=0)
+        pv = p.astype(v.dtype).astype(jnp.float32) * v.astype(jnp.float32)
+        acc_ref[g] = acc_ref[g] * alpha + jnp.sum(pv, axis=0)
+        m_ref[g] = m_new
 
     @pl.when(j == npg - 1)
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)   # all-masked lane: garbage,
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)  # discarded
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)   # discarded
 
 
 def paged_flash_decode(q4, pool_k, pool_v, block_tables, bias, *,
@@ -100,37 +107,40 @@ def paged_flash_decode(q4, pool_k, pool_v, block_tables, bias, *,
     ps = pool_k.shape[1]
     r = block_tables.shape[1]
     assert bias.shape == (b, r * ps), (bias.shape, b, r, ps)
+    # group-major q (a kv head's query rows are one (KV, hd) slab per
+    # group) and one (ps, 1, 1) bias column per page: both blocks then
+    # keep their array's last two dims whole
+    qg = q4.transpose(0, 2, 1, 3)                    # (B, G, KV, hd)
+    bias5 = bias.reshape(b, r, ps, 1, 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, r),
+        grid=(b, r),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda i, h, j, bt: (i, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda i, h, j, bt: (bt[i, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda i, h, j, bt: (bt[i, j], 0, h, 0)),
-            pl.BlockSpec((1, ps), lambda i, h, j, bt: (i, j)),
+            pl.BlockSpec((1, g, kvh, hd), lambda i, j, bt: (i, 0, 0, 0)),
+            pl.BlockSpec((1, ps, kvh, hd),
+                         lambda i, j, bt: (bt[i, j], 0, 0, 0)),
+            pl.BlockSpec((1, ps, kvh, hd),
+                         lambda i, j, bt: (bt[i, j], 0, 0, 0)),
+            pl.BlockSpec((1, 1, ps, 1, 1),
+                         lambda i, j, bt: (i, j, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda i, h, j, bt: (i, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, hd), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32)],
+        out_specs=pl.BlockSpec((1, g, kvh, hd),
+                               lambda i, j, bt: (i, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((g, kvh, hd), jnp.float32),
+                        pltpu.VMEM((g, kvh, 1), jnp.float32),
+                        pltpu.VMEM((g, kvh, 1), jnp.float32)],
     )
-    kwargs = {}
-    if _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     kernel = functools.partial(_paged_decode_kernel, scale, softcap)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, g, kvh, hd), jnp.float32),
         interpret=interpret,
-        **kwargs,
-    )(block_tables, q4, pool_k, pool_v, bias)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(block_tables, qg, pool_k, pool_v, bias5)
+    return out.transpose(0, 2, 1, 3)
 
 
 def mask_bias(posb, kpos, window: int = 0) -> jax.Array:

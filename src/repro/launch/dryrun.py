@@ -25,24 +25,12 @@ from repro.configs import SHAPES, cells, get_config, skip_shapes
 from repro.distributed import sharding as shd
 from repro.distributed.context import DistContext
 from repro.launch import specs as specs_mod
-from repro.launch.mesh import make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_production_mesh, shard_blocks
 from repro.models import registry
 from repro.optim import adamw
 from repro.roofline import analysis
 from repro.training import step as train_step_mod
-
-
-def _state_shardings(cfg, mesh):
-    pspecs = registry.param_specs(cfg)
-    p_shd = shd.param_sharding_tree(pspecs, mesh)
-    masks_abs = train_step_mod.abstract_state(cfg).masks
-    m_shd = shd.mask_sharding_tree(masks_abs, registry.axes_tree(cfg),
-                                   registry.sparse_paths(cfg), mesh) \
-        if cfg.blast.enabled else {}
-    rep = NamedSharding(mesh, P())
-    return train_step_mod.TrainState(
-        step=rep, params=p_shd,
-        opt_state={"m": p_shd, "v": p_shd}, masks=m_shd, rng=rep)
 
 
 def lower_cell(arch: str, shape_name: str, multi_pod: bool,
@@ -51,6 +39,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(mesh.devices.size)
     cfg, shape, inputs = specs_mod.input_specs(arch, shape_name)
+    cfg = shard_blocks(cfg, mesh)
     # §Perf experiment knobs (baseline = all unset)
     import dataclasses as _dc
     overrides = {}
@@ -86,7 +75,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                            if not os.environ.get("DRYRUN_NOCOMPRESS")
                            else {}},
                 masks=state_abs.masks, rng=state_abs.rng)
-        state_shd = _state_shardings(cfg, mesh)
+        state_shd = train_step_mod.state_sharding(cfg, mesh)
         if os.environ.get("DRYRUN_DEFERRED") \
                 and not os.environ.get("DRYRUN_NOCOMPRESS"):
             state_shd = train_step_mod.TrainState(
@@ -211,6 +200,7 @@ def main():
                     help="print 'arch shape mesh' rows and exit (used by "
                          "the per-cell-subprocess sweep driver)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.list_cells:
         for arch, shape in cells():

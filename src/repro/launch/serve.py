@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import dataclasses
+import functools
 import time
 
 import jax
@@ -120,11 +121,10 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config
-    from repro.core import sparse_mlp as sm
-    from repro.models import registry
-    from repro.serving import engine, export, serve_loop
-    from repro.training import step as ts
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serving import export
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
 
     if args.validate_only and not args.artifact:
@@ -146,33 +146,12 @@ def main():
         _serve(cfg, params, args)
         return
 
-    state = ts.init_state(cfg, jax.random.PRNGKey(0))
-    if args.ckpt_dir:
-        from repro.checkpointing.checkpoint import Checkpointer
-        state = Checkpointer(args.ckpt_dir).restore_state(state)
-    elif cfg.blast.enabled:
-        # no checkpoint: one-shot magnitude prune at --sparsity
-        spec = dataclasses.replace(cfg.blast, s_init=args.sparsity,
-                                   s_max=args.sparsity)
-        masks = {}
-        from repro.core.prune_grow import initial_mask
-        import dataclasses as dc
-        for path in registry.sparse_paths(cfg):
-            w = state.params[path.split("/")[0]]
-            w = sm.get_path(state.params, path)
-            bi, bo = sm.block_dims_for(spec, path)
-            pspec = dc.replace(spec, b_in=bi, b_out=bo)
-            fn = lambda wi: initial_mask(pspec, wi)
-            for _ in range(w.ndim - 2):
-                fn = jax.vmap(fn)
-            masks[path] = fn(w)
-        state = dataclasses.replace(state, masks=masks)
-
+    params, masks = served_params(cfg, sparsity=args.sparsity,
+                                  ckpt_dir=args.ckpt_dir)
     pad_report: dict = {}
-    params = (export.pack_params(cfg, state.params, state.masks,
+    params = (export.pack_params(cfg, params, masks,
                                  pad_report=pad_report)
-              if args.packed else
-              export.prune_params(cfg, state.params, state.masks))
+              if args.packed else export.prune_params(cfg, params, masks))
     print("serving memory:", export.memory_report(cfg, params))
 
     if args.seal:
@@ -189,6 +168,46 @@ def main():
         return
 
     _serve(cfg, params, args)
+
+
+def served_params(cfg, *, seed: int = 0, sparsity: float = 0.8,
+                  ckpt_dir: str | None = None):
+    """The dense bf16 serving weights and their BLaST block masks, with
+    no optimizer state: restored from ``ckpt_dir``, or made from
+    ``seed`` with a one-shot magnitude prune at ``sparsity``. Float
+    leaves arrive in bf16 leaf by leaf, so the device never holds a
+    float32 copy of the model (stablelm-3b: 5.6 GB in bf16, where the
+    float32 params plus AdamW moments are 33.6 GB)."""
+    from repro.core import sparse_mlp as sm
+    from repro.core.prune_grow import initial_mask
+    from repro.models import registry
+    from repro.training import step as ts
+
+    if ckpt_dir:
+        from repro.checkpointing.checkpoint import Checkpointer
+        abstract = ts.abstract_state(cfg)
+        tree = Checkpointer(ckpt_dir).restore(
+            {"params": abstract.params, "masks": abstract.masks})
+        params = jax.tree_util.tree_map(
+            lambda x: jnp.asarray(x, jnp.bfloat16
+                                  if x.dtype == np.float32 else x.dtype),
+            tree["params"])
+        return params, jax.tree_util.tree_map(jnp.asarray, tree["masks"])
+    params = registry.init_params(cfg, jax.random.PRNGKey(seed),
+                                  dtype=jnp.bfloat16)
+    masks = {}
+    for path in (registry.sparse_paths(cfg) if cfg.blast.enabled else []):
+        w = sm.get_path(params, path)
+        bi, bo = sm.block_dims_for(cfg.blast, path)
+        spec = dataclasses.replace(cfg.blast, s_init=sparsity,
+                                   s_max=sparsity, b_in=bi, b_out=bo)
+        # one layer (expert) at a time: over a whole stack the f32 copy
+        # behind the block norms is 2.3 GB per stablelm-3b MLP weight
+        fn = lambda wi: initial_mask(spec, wi)
+        for _ in range(w.ndim - 2):
+            fn = functools.partial(jax.lax.map, fn)
+        masks[path] = jax.jit(fn)(w)
+    return params, masks
 
 
 def _serve(cfg, params, args):
@@ -243,6 +262,9 @@ def _serve(cfg, params, args):
              if args.mixed else ""))
     for p, t in list(zip(prompts, toks))[:2]:
         print(t[p.size:])
+    mem = jax.devices()[0].memory_stats() or {}
+    if "peak_bytes_in_use" in mem:
+        print(f"peak device bytes: {mem['peak_bytes_in_use']:,}")
     _write_trace(args, tracer)
 
 

@@ -3,10 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-3b \
         --smoke --steps 100 --batch 8 --seq 128 [--ckpt-dir ckpts/]
 
-``--smoke`` selects the reduced config (CPU-runnable). On a real TPU
-fleet the same entry point runs the full config on the production mesh
-(--mesh single|multi selects it; jax.distributed.initialize is called
-when JAX_COORDINATOR is set).
+``--smoke`` selects the reduced config (CPU-runnable). ``--mesh single``
+shards the state GSPMD-style over one (data, model) mesh of the devices
+there are (``launch/mesh.py make_host_mesh``: every device on the model
+axis), with BLaST blocks derived for one device's shard
+(``shard_blocks``); jax.distributed.initialize is called when
+JAX_COORDINATOR is set. ``main(argv)`` returns the final state and the
+history, so a caller in the same process drives the same path.
 """
 from __future__ import annotations
 
@@ -15,7 +18,7 @@ import dataclasses
 import os
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -30,11 +33,10 @@ def main():
                     help="disable the anomaly guard (device-side skip "
                          "+ host-side spike/rewind policy)")
     ap.add_argument("--data", default=None, help="memmap token file")
-    ap.add_argument("--mesh", choices=["none", "single", "multi"],
-                    default="none")
+    ap.add_argument("--mesh", choices=["none", "single"], default="none")
     ap.add_argument("--devices", type=int, default=0,
                     help="force host platform device count")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.devices:
         os.environ["XLA_FLAGS"] = (
@@ -48,11 +50,16 @@ def main():
     from repro.configs.base import ShapeConfig
     from repro.data.pipeline import make_source
     from repro.distributed.context import DistContext
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_host_mesh, shard_blocks
     from repro.optim import adamw
     from repro.training import train_loop
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
+    mesh = make_host_mesh() if args.mesh == "single" else None
+    if mesh is not None:
+        cfg = shard_blocks(cfg, mesh)
     overrides = {}
     if args.s_max is not None:
         overrides["s_max"] = args.s_max
@@ -62,10 +69,7 @@ def main():
         cfg = dataclasses.replace(cfg, blast=dataclasses.replace(
             cfg.blast, total_steps=args.steps, **overrides))
 
-    mesh = None
-    if args.mesh != "none":
-        mesh = make_production_mesh(multi_pod=args.mesh == "multi")
-    dist = DistContext(mesh=mesh) if mesh else None
+    dist = DistContext(mesh=mesh) if mesh is not None else None
 
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     source = make_source(cfg, shape, path=args.data)
@@ -78,6 +82,7 @@ def main():
     state, history = train_loop.train(cfg, opt, source, loop, dist=dist)
     print(f"done: final loss {history[-1]['loss']:.4f}, "
           f"sparsity {history[-1]['sparsity']:.3f}")
+    return state, history
 
 
 if __name__ == "__main__":

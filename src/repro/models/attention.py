@@ -44,11 +44,15 @@ def attn_param_specs(cfg, cross: bool = False) -> dict:
     d, hd = cfg.d_model, cfg.head_dim
     h, kv = eff_heads(cfg)
     specs = {
-        "wq": ParamSpec((d, h, hd), ("embed", "heads", "head_dim")),
-        "wk": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim")),
+        "wq": ParamSpec((d, h, hd), ("embed", "heads", "head_dim"),
+                        fan_in=d),
+        "wk": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim"),
+                        fan_in=d),
+        "wv": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim"),
+                        fan_in=d),
         "wo": ParamSpec((h, hd, d), ("heads", "head_dim", "embed"),
-                        scale=1.0 / math.sqrt(2 * cfg.num_layers)),
+                        scale=1.0 / math.sqrt(2 * cfg.num_layers),
+                        fan_in=h * hd),
     }
     if cfg.qkv_bias:
         specs["bq"] = ParamSpec((h, hd), ("heads", "head_dim"), init="zeros")

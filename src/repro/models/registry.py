@@ -22,8 +22,8 @@ def param_specs(cfg):
     return module_for(cfg).param_specs(cfg)
 
 
-def init_params(cfg, rng):
-    return pmod.init_params(param_specs(cfg), rng)
+def init_params(cfg, rng, dtype=None):
+    return pmod.init_params(param_specs(cfg), rng, dtype)
 
 
 def abstract_params(cfg):

@@ -12,6 +12,7 @@ cache fits (DESIGN.md §5).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any
@@ -68,8 +69,8 @@ def layer_param_specs(cfg) -> dict:
 def _stack_specs(specs: dict, n: int) -> dict:
     """Prepend a stacked 'layers' dim to every leaf."""
     def f(s: ParamSpec) -> ParamSpec:
-        return ParamSpec((n,) + s.shape, ("layers",) + s.axes,
-                         init=s.init, scale=s.scale, dtype=s.dtype)
+        return dataclasses.replace(s, shape=(n,) + s.shape,
+                                   axes=("layers",) + s.axes)
     return jax.tree_util.tree_map(
         f, specs, is_leaf=lambda x: isinstance(x, ParamSpec))
 
@@ -139,7 +140,6 @@ def _moe_shardmap(cfg, p, x, masks, dist):
     """EP over the model axis: tokens replicated across 'model', local
     experts per shard, psum combine (DESIGN.md §4)."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.context import shard_map
     ma = dist.model_axis
     bp = dist.batch_pspec(3)
     rep = P()
@@ -159,9 +159,10 @@ def _moe_shardmap(cfg, p, x, masks, dist):
             aux = jax.lax.pmean(aux, dist.batch_axes)
         return y, aux
 
-    y, aux = shard_map(body, mesh=dist.mesh,
-                       in_specs=(bp, p_specs, m_specs),
-                       out_specs=(bp, rep), check_vma=False)(x, p, masks)
+    y, aux = jax.shard_map(body, mesh=dist.mesh,
+                           in_specs=(bp, p_specs, m_specs),
+                           out_specs=(bp, rep),
+                           check_vma=False)(x, p, masks)
     return y, aux
 
 

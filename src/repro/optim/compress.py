@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.context import shard_map
-
 
 def quantize_int8(g: jax.Array, err: jax.Array):
     """-> (q int8, scale f32 scalar, new_err). g+err is quantized."""
@@ -77,8 +75,8 @@ def compressed_psum(grads, err, mesh, axes: tuple[str, ...]):
 
     specs = tuple(P() for _ in flat_g + flat_e)
     out_specs = (tuple(P() for _ in flat_g), tuple(P() for _ in flat_g))
-    f = shard_map(mapped, mesh=mesh, in_specs=specs,
-                  out_specs=out_specs, check_vma=False)
+    f = jax.shard_map(mapped, mesh=mesh, in_specs=specs,
+                      out_specs=out_specs, check_vma=False)
     red, new_e = f(*flat_g, *flat_e)
     return (tdef.unflatten(list(red)), tdef.unflatten(list(new_e)))
 

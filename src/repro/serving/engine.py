@@ -447,6 +447,10 @@ class Engine:
         # map this to killing the device stream)
         self._condemned = threading.Event()
         self._faults = None
+        # optional callback ``{uid: last-position logits}`` after each
+        # prefill: lets a check against a reference read the logits the
+        # engine itself turned into each request's first token
+        self.prefill_logits_hook = None
         self.pcache: PrefixCache | None = None
         # lanes frozen off-device by preemption, awaiting restore (any
         # paged engine can be preempted explicitly via ``preempt()``;
@@ -1266,6 +1270,9 @@ class Engine:
             self.stats["prefill_chunks"] += 1
         first = np.asarray(jax.block_until_ready(jnp.argmax(last, -1)))
         now = time.monotonic()
+        if self.prefill_logits_hook is not None:
+            self.prefill_logits_hook(
+                {self.lanes[i].req.uid: last[i] for i in lane_ids})
         self.stats["prefill_s"] += now - t0
         if self.tracer.enabled:
             # span from the timestamps this loop already took at its

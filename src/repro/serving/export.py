@@ -10,6 +10,7 @@
 """
 from __future__ import annotations
 
+import functools
 import warnings
 
 import jax
@@ -27,15 +28,24 @@ class UnbalancedMaskWarning(UserWarning):
     1/(1-s) memory reduction silently degrades by the pad fraction."""
 
 
+def _cast(tree, dtype):
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(dtype) if x.dtype == jnp.float32 else x, tree)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _prune_leaf(w, m, b_in, b_out, dtype):
+    # one fused pass: the pruned leaf is the only new buffer
+    return _cast(topk.apply_block_mask(w, m, b_in, b_out), dtype)
+
+
 def prune_params(cfg, params, masks, dtype=jnp.bfloat16):
     out = params
     for path, m in masks.items():
-        w = sm.get_path(params, path)
         bi, bo = sm.block_dims_for(cfg.blast, path)
-        out = sm.set_path(out, path,
-                          topk.apply_block_mask(w, m, bi, bo))
-    return jax.tree_util.tree_map(
-        lambda x: x.astype(dtype) if x.dtype == jnp.float32 else x, out)
+        out = sm.set_path(out, path, _prune_leaf(sm.get_path(params, path),
+                                                 m, bi, bo, dtype))
+    return _cast(out, dtype)
 
 
 def pack_params(cfg, params, masks, dtype=jnp.bfloat16,
@@ -56,10 +66,11 @@ def pack_params(cfg, params, masks, dtype=jnp.bfloat16,
     if unbalanced not in ("warn", "raise", "ignore"):
         raise ValueError(f"unbalanced={unbalanced!r}: expected "
                          "'warn', 'raise' or 'ignore'")
-    pruned = prune_params(cfg, params, masks, dtype)
-    out = pruned
+    # packs the unpruned leaves: the pack keeps only the mask's blocks,
+    # so no pruned dense copy of the model is ever made
+    out = params
     for path, m in masks.items():
-        w = sm.get_path(pruned, path)
+        w = sm.get_path(params, path)
         bi, bo = sm.block_dims_for(cfg.blast, path)
         counts = np.asarray(jax.device_get(m)).sum(axis=-2)
         nnz = int(counts.max())
@@ -87,7 +98,7 @@ def pack_params(cfg, params, masks, dtype=jnp.bfloat16,
                                     sm.get_path(out, upath))
         out = sm.set_path(out, gpath, pg)
         out = sm.set_path(out, upath, pu)
-    return out
+    return _cast(out, dtype)
 
 
 def abstract_packed_params(cfg, sparsity: float, mesh=None):

@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import sparse_mlp as sm
-from repro.distributed.context import (HAS_PARTIAL_MANUAL, DistContext,
-                                       shard_map)
+from repro.distributed.context import DistContext
 from repro.models import registry
 from repro.optim import adamw, compress
 from repro.training.step import TrainState, loss_fn
@@ -33,10 +32,6 @@ def make_train_step_deferred(cfg, opt_cfg: adamw.AdamWConfig, mesh,
 
     opt_state grows an 'ef' tree (error-feedback residuals) when
     compression is on — init via ``init_opt_state``."""
-    if not HAS_PARTIAL_MANUAL:
-        raise NotImplementedError(
-            "deferred reduction needs partial-manual shard_map "
-            "(axis_names), unsupported by this jax version")
     spec = cfg.blast
     dense_flags = registry.dense_layer_flags(cfg) if spec.enabled else None
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -109,12 +104,6 @@ def make_train_step_deferred(cfg, opt_cfg: adamw.AdamWConfig, mesh,
     # manual over data; params/opt/masks ride along on the Auto model
     # axis (specs must not mention Auto axes)
     rep = P()
-    state_spec = TrainState(
-        step=rep,
-        params=jax.tree_util.tree_map(lambda _: rep,
-                                      registry.abstract_params(cfg)),
-        opt_state=None, masks=None, rng=rep)
-    # build full spec trees lazily inside the wrapper instead:
 
     def train_step(state: TrainState, batch):
         st_spec = jax.tree_util.tree_map(lambda _: rep, state)
@@ -125,12 +114,11 @@ def make_train_step_deferred(cfg, opt_cfg: adamw.AdamWConfig, mesh,
         out_spec = (jax.tree_util.tree_map(lambda _: rep, state),
                     {"loss": rep, "aux": rep, "sparsity": rep,
                      "grad_norm": rep, "lr": rep})
-        f = shard_map(body, mesh=mesh, in_specs=(st_spec, b_spec),
-                      out_specs=out_spec, check_vma=False,
-                      axis_names=set(data_axes))
+        f = jax.shard_map(body, mesh=mesh, in_specs=(st_spec, b_spec),
+                          out_specs=out_spec, check_vma=False,
+                          axis_names=set(data_axes))
         return f(state, batch)
 
-    del state_spec
     return train_step
 
 

@@ -231,10 +231,14 @@ def run_child(spec: dict, spec_path: str,
     """Write ``spec`` to ``spec_path`` and run the chaos child on it in
     a subprocess (so a ``hard_kill`` SIGKILLs the child, not the
     caller). Returns the CompletedProcess; a killed child has
-    ``returncode == -SIGKILL``."""
+    ``returncode == -SIGKILL``.
+
+    The child is pinned to the CPU: it tests recovery semantics, not
+    speed, and an accelerator belongs to one process at a time — the
+    caller may already hold it."""
     with open(spec_path, "w") as f:
         json.dump(spec, f)
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (_src_root() + os.pathsep
                          + env.get("PYTHONPATH", ""))
     return subprocess.run(

@@ -55,6 +55,24 @@ def abstract_state(cfg) -> TrainState:
         rng=jax.ShapeDtypeStruct((2,), jnp.uint32))
 
 
+def state_sharding(cfg, mesh) -> TrainState:
+    """NamedSharding tree of a TrainState on ``mesh``: params and both
+    AdamW moments by the logical-axis rules, masks like their weights,
+    step and rng replicated. ``jax.jit(init_state, out_shardings=...)``
+    with it creates the state sharded, never whole on one device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.distributed import sharding as shd
+    p_shd = shd.param_sharding_tree(registry.param_specs(cfg), mesh)
+    m_shd = shd.mask_sharding_tree(
+        abstract_state(cfg).masks, registry.axes_tree(cfg),
+        registry.sparse_paths(cfg), mesh) if cfg.blast.enabled else {}
+    rep = NamedSharding(mesh, P())
+    return TrainState(step=rep, params=p_shd,
+                      opt_state={"m": p_shd, "v": p_shd}, masks=m_shd,
+                      rng=rep)
+
+
 def loss_fn(cfg, params, masks, batch, teacher_logits=None,
             kd_alpha=1.0, kd_beta=0.0, dist=None):
     kw = {}

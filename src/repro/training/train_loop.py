@@ -28,6 +28,7 @@ Fault tolerance model (DESIGN.md §4, hardened per ISSUE 8):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import signal
 import sys
 import time
@@ -87,11 +88,22 @@ def train(cfg, opt_cfg: adamw.AdamWConfig, source, loop: TrainLoopConfig,
         teacher_cfg=teacher_cfg, teacher_params_static=teacher_params,
         guard=gcfg is not None,
         grad_norm_limit=gcfg.grad_norm_limit if gcfg else None)
+    # on a mesh the state is created, stepped and restored sharded: a
+    # model whose state only fits split across the devices never lands
+    # whole on one of them
+    shd = (step_mod.state_sharding(cfg, dist.mesh)
+           if dist is not None and dist.mesh is not None else None)
+    if shd is not None and jit_kwargs is None:
+        jit_kwargs = dict(in_shardings=(shd, None),
+                          out_shardings=(shd, None))
     step_fn = jax.jit(train_step, donate_argnums=(0,),
                       **(jit_kwargs or {}))
 
     if state is None:
-        state = step_mod.init_state(cfg, jax.random.PRNGKey(0))
+        init = functools.partial(step_mod.init_state, cfg)
+        if shd is not None:
+            init = jax.jit(init, out_shardings=shd)
+        state = init(jax.random.PRNGKey(0))
 
     ckpt = Checkpointer(loop.ckpt_dir, keep=loop.keep) \
         if loop.ckpt_dir else None
@@ -101,7 +113,7 @@ def train(cfg, opt_cfg: adamw.AdamWConfig, source, loop: TrainLoopConfig,
             ckpt.fault_hook = faults.on_ckpt_saved
     start = 0
     if ckpt and ckpt.latest_intact_step() is not None:
-        state = ckpt.restore_state(state)
+        state = ckpt.restore_state(state, shardings=shd)
         start = int(np.asarray(state.step))
         print(f"[resume] restored step {start} from {loop.ckpt_dir}")
 
@@ -193,7 +205,7 @@ def train(cfg, opt_cfg: adamw.AdamWConfig, source, loop: TrainLoopConfig,
                         tr.postmortem("train_rewind", step=i,
                                       consecutive=guard.consecutive,
                                       rewinds=guard.counters["rewinds"])
-                        state = ckpt.restore_state(state)
+                        state = ckpt.restore_state(state, shardings=shd)
                         counters["ckpt_fallbacks"] = ckpt.fallbacks
                         new_i = int(np.asarray(state.step))
                         guard.note_rewind(i, new_i)

@@ -15,10 +15,11 @@ import jax, jax.numpy as jnp, numpy as np
 import sys
 sys.path.insert(0, "tests")
 from conftest import tiny_cfg
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.training import step as ts, deferred
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = tiny_cfg(num_heads=4, num_kv_heads=2, d_model=64, d_ff=128,
                head_dim=16)
 opt = adamw.AdamWConfig(total_steps=20, warmup_steps=0)
@@ -58,10 +59,6 @@ print(json.dumps({
 
 @pytest.mark.slow
 def test_deferred_matches_gspmd_step():
-    from repro.distributed.context import HAS_PARTIAL_MANUAL
-    if not HAS_PARTIAL_MANUAL:
-        pytest.skip("partial-manual shard_map (axis_names) unsupported "
-                    "on this jax; the auto= spelling crashes XLA 0.4.x")
     env = dict(os.environ, PYTHONPATH="src" + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     out = subprocess.run([sys.executable, "-c", _SCRIPT],

@@ -11,9 +11,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def test_spec_for_divisibility():
-    import jax
     from repro.distributed.sharding import spec_for
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     # kv=4 heads on a 1-wide model axis: divisible -> sharded
     assert spec_for((4, 16), ("kv_heads", "head_dim"), mesh) == \
         P("model", None)
@@ -22,11 +22,34 @@ def test_spec_for_divisibility():
 def test_spec_for_fallback_replicates():
     import jax
     from repro.distributed.sharding import spec_for
+    from repro.launch.mesh import make_mesh
     if len(jax.devices()) != 1:
         pytest.skip("needs single-device run")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # 3 not divisible by nothing... size-1 axes always divide
     assert spec_for((3,), ("ff",), mesh) == P("model")
+
+
+@pytest.mark.parametrize("model_axis, blocks",
+                         [(1, (128, 128)), (4, (128, 64)), (16, (128, 16))])
+def test_shard_blocks_follow_model_axis(model_axis, blocks):
+    """BLaST blocks tile the d_ff shard one device holds (stablelm-3b:
+    6912 / model_axis); the rest of the schedule is kept."""
+    import dataclasses
+    from jax.sharding import AbstractMesh
+    from repro.configs import get_config
+    from repro.launch.mesh import shard_blocks
+    cfg = get_config("stablelm-3b")
+    cfg = dataclasses.replace(cfg, blast=dataclasses.replace(
+        cfg.blast, total_steps=7, s_max=0.5))
+    out = shard_blocks(cfg, AbstractMesh((1, model_axis),
+                                         ("data", "model")))
+    assert (out.blast.b_in, out.blast.b_out) == blocks
+    assert (out.blast.total_steps, out.blast.s_max) == (7, 0.5)
+    dense = dataclasses.replace(cfg, blast=dataclasses.replace(
+        cfg.blast, enabled=False))
+    assert shard_blocks(dense, AbstractMesh((1, 4), ("data", "model"))) \
+        is dense
 
 
 _EQUIV_SCRIPT = r"""
@@ -34,13 +57,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 import sys
 sys.path.insert(0, "tests")
 from conftest import tiny_cfg
 from repro.distributed import sharding as shd
 from repro.distributed.context import DistContext
-from repro.models import registry
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.training import step as ts
 
@@ -60,16 +82,9 @@ step1 = jax.jit(ts.make_train_step(cfg, opt))
 _, m1 = step1(state, batch)
 
 # 2x4 mesh sharded
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 dist = DistContext(mesh=mesh)
-p_shd = shd.param_sharding_tree(registry.param_specs(cfg), mesh)
-rep = NamedSharding(mesh, P())
-m_shd = shd.mask_sharding_tree(ts.abstract_state(cfg).masks,
-                               registry.axes_tree(cfg),
-                               registry.sparse_paths(cfg), mesh)
-state_shd = ts.TrainState(step=rep, params=p_shd,
-                          opt_state={"m": p_shd, "v": p_shd},
-                          masks=m_shd, rng=rep)
+state_shd = ts.state_sharding(cfg, mesh)
 batch_shd = {k: shd.batch_sharding(mesh, v.ndim, v.shape[0])
              for k, v in batch.items()}
 with mesh:

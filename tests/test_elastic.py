@@ -14,12 +14,11 @@ import os, sys, json, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, "tests")
-from jax.sharding import NamedSharding, PartitionSpec as P
 from conftest import tiny_cfg
 from repro.checkpointing.checkpoint import Checkpointer
 from repro.distributed import sharding as shd
 from repro.distributed.context import DistContext
-from repro.models import registry
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.training import step as ts
 
@@ -31,19 +30,9 @@ batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
          "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 32), 0,
                                       cfg.vocab_size)}
 
-def shardings(mesh):
-    p_shd = shd.param_sharding_tree(registry.param_specs(cfg), mesh)
-    rep = NamedSharding(mesh, P())
-    m_shd = shd.mask_sharding_tree(ts.abstract_state(cfg).masks,
-                                   registry.axes_tree(cfg),
-                                   registry.sparse_paths(cfg), mesh)
-    return ts.TrainState(step=rep, params=p_shd,
-                         opt_state={"m": p_shd, "v": p_shd},
-                         masks=m_shd, rng=rep)
-
 def run_step(mesh, state):
     dist = DistContext(mesh=mesh)
-    s_shd = shardings(mesh)
+    s_shd = ts.state_sharding(cfg, mesh)
     b_shd = {k: shd.batch_sharding(mesh, v.ndim, v.shape[0])
              for k, v in batch.items()}
     with mesh:
@@ -54,15 +43,14 @@ def run_step(mesh, state):
 
 d = tempfile.mkdtemp()
 # step 0 on the BIG mesh (2x4 = "two pods"), checkpoint
-big = jax.make_mesh((2, 4), ("data", "model"))
+big = make_mesh((2, 4), ("data", "model"))
 state = ts.init_state(cfg, jax.random.PRNGKey(0))
 state, m0 = run_step(big, state)
 ck = Checkpointer(d)
 ck.save(1, state, blocking=True)
 
 # "lose a pod": restore onto a 2x2 mesh built from 4 devices
-small = jax.sharding.Mesh(
-    np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+small = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 tmpl = ts.init_state(cfg, jax.random.PRNGKey(0))
 restored = ck.restore_state(tmpl, shardings=None)
 restored = jax.tree_util.tree_map(jnp.asarray, restored)

@@ -77,8 +77,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, sys
 sys.path.insert(0, "src")
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.roofline import hlo_cost
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 def step(x, w):
     return jax.lax.scan(lambda c, wi: (c @ wi, None), x, w)[0]
 xs = jnp.ones((16, 256)); ws = jnp.ones((6, 256, 256))

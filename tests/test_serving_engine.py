@@ -70,6 +70,28 @@ def test_ragged_prompts_match_solo_generation(model, slab_k):
         np.testing.assert_array_equal(g, np.asarray(want)[0])
 
 
+def test_prefill_logits_hook_reports_each_request(model):
+    """The hook sees, per request, the prefill logits whose argmax is
+    that request's first served token, and matches the dense forward
+    at the prompt's last position."""
+    cfg, params = model
+    prompts = _prompts(cfg, [5, 8, 3])
+    eng = engine.Engine(cfg, params, max_batch=2, max_len=16,
+                        prefill_chunk=4)
+    seen = {}
+    eng.prefill_logits_hook = seen.update
+    uids = [eng.submit(p, 3) for p in prompts]
+    res = eng.run()
+    assert sorted(seen) == sorted(uids)
+    for u, p in zip(uids, prompts):
+        got = np.asarray(seen[u], np.float32)
+        assert int(got.argmax()) == int(res[u].generated[0])
+        want, _ = registry.forward(cfg, params, jnp.asarray(p)[None],
+                                   masks=None)
+        np.testing.assert_allclose(got, np.asarray(want[0, -1], np.float32),
+                                   atol=2e-2 * float(np.abs(got).max()))
+
+
 def test_slab_sizes_bitwise_identical_under_continuous_admission(model):
     """Ragged continuous-admission workload: 6 requests over 2 lanes
     with different budgets — the slab engine (K=4, 16) must emit exactly

@@ -80,6 +80,24 @@ def test_export_packed_matches_pruned(tmp_path):
     np.testing.assert_array_equal(t1, t2)
 
 
+def test_pack_params_ignores_pruned_blocks():
+    """``pack_params`` keeps only the mask's blocks, so packing the
+    unpruned weights gives exactly the pack of the pruned ones."""
+    from repro.launch.serve import served_params
+    from repro.serving import export
+    cfg = tiny_cfg()
+    params, masks = served_params(cfg, seed=1, sparsity=0.5)
+    assert not all(bool(m.all()) for m in masks.values())
+    pruned = export.prune_params(cfg, params, masks)
+    a = export.pack_params(cfg, params, masks)
+    b = export.pack_params(cfg, pruned, masks)
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 def test_distillation_reduces_kl():
     """Post-training compression (paper §5.2): student with KD matches
     teacher logits better than CE-only student."""
