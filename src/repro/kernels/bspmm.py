@@ -82,6 +82,7 @@ def bspmm(x: jax.Array, packed: PackedBCSC, *, blk_m: int = 128,
     return pl.pallas_call(
         _bspmm_kernel,
         grid_spec=grid_spec,
+        name="bspmm",
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -177,6 +178,7 @@ def _fused_glu_joint(x, p_gate, p_up, *, act, blk_m, interpret):
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="fused_glu",
         out_shape=jax.ShapeDtypeStruct((m, nb * b_out), x.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -232,6 +234,7 @@ def fused_glu(x: jax.Array, p_gate: PackedBCSC, p_up: PackedBCSC, *,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="fused_glu",
         out_shape=jax.ShapeDtypeStruct((m, nb * b_out), x.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(

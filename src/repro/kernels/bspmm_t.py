@@ -80,6 +80,7 @@ def _bspmm_t_call(dy, blocks, idx, first, kb, *, blk_m=128,
     return pl.pallas_call(
         _bspmm_t_kernel,
         grid_spec=grid_spec,
+        name="bspmm_t",
         out_shape=jax.ShapeDtypeStruct((m, kb * b_in), dy.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(

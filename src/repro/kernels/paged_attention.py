@@ -135,6 +135,7 @@ def paged_flash_decode(q4, pool_k, pool_v, block_tables, bias, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="paged_flash_decode",
         out_shape=jax.ShapeDtypeStruct((b, g, kvh, hd), jnp.float32),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(

@@ -272,7 +272,8 @@ def _write_trace(args, tracer):
     if tracer is None:
         return
     from repro.obs.export import write_chrome_trace
-    write_chrome_trace(args.trace_out, tracer.records)
+    write_chrome_trace(args.trace_out, tracer.records,
+                       offset_s=tracer.clock_offset)
     print(f"wrote {len(tracer.records)} spans to {args.trace_out} "
           f"(open in https://ui.perfetto.dev)")
 

@@ -7,6 +7,12 @@ decode with sequence-sharded KV caches, and optional cross-attention
 Head padding: archs whose head count does not divide TP=16 declare
 ``pad_heads_to``; extra heads are zero-initialised (wo rows zero ⇒ the
 padding is numerically exact) — DESIGN.md §5.
+
+Every attention function runs under the named scope ``attn``, and its
+parts under ``attn.qkv``, ``attn.kv_write``, ``attn.kv_read``,
+``attn.core`` and ``attn.out`` (``obs.trace.SCOPES``): a device trace
+attributes each op to its part by the ``op_name`` these leave in the
+HLO metadata. Scopes change no computation.
 """
 from __future__ import annotations
 
@@ -85,6 +91,7 @@ def _project_qkv(cfg, p, x, kv_src=None):
     return q, k, v
 
 
+@jax.named_scope("attn.core")
 def _scores_to_out(cfg, q, k, v, q_pos, k_pos, causal, window):
     """Grouped attention core. q: (B,Sq,H,hd); k/v: (B,Sk,KV,hd);
     q_pos: (B,Sq); k_pos: (B,Sk) (for masking). Returns (B,Sq,H,hd).
@@ -121,17 +128,19 @@ def _scores_to_out(cfg, q, k, v, q_pos, k_pos, causal, window):
     return out.reshape(b, sq, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("attn")
 def multihead_attention(cfg, p, x, positions, *, causal=True, window=0,
                         q_chunk=1024, kv_src=None, kv_positions=None):
     """Full (train/prefill/encoder) attention with query chunking.
 
     Returns (out (B,S,D), (k, v)) — k/v returned so prefill can seed the
     cache."""
-    q, k, v = _project_qkv(cfg, p, x, kv_src)
-    if cfg.rope_theta > 0 and kv_src is None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions if kv_positions is None
-                       else kv_positions, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(cfg, p, x, kv_src)
+        if cfg.rope_theta > 0 and kv_src is None:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions if kv_positions is None
+                           else kv_positions, cfg.rope_theta)
     kpos = positions if kv_positions is None else kv_positions
     s = q.shape[1]
     if s <= q_chunk or s % q_chunk != 0:
@@ -148,7 +157,8 @@ def multihead_attention(cfg, p, x, positions, *, causal=True, window=0,
         _, outs = jax.lax.scan(chunk, None,
                                (qs.swapaxes(0, 1), ps.swapaxes(0, 1)))
         out = outs.swapaxes(0, 1).reshape(q.shape)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, (k, v)
 
 
@@ -167,6 +177,7 @@ def _cache_positions(smax: int, offsets: jax.Array) -> jax.Array:
     return jnp.where(slots >= off, slots - off, jnp.int32(_PAD_POS))
 
 
+@jax.named_scope("attn")
 def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, window=0,
                      cross=False, offsets=None):
     """One-token decode. x: (B,1,D); cache_k/v: (B,Smax,KV,hd); ``pos``
@@ -193,43 +204,49 @@ def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, window=0,
         posb = (posv - offsets.astype(jnp.int32))[:, None]
     if cross:
         # encoder memory is already projected K/V; only project Q
-        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
-        if cfg.qkv_bias:
-            q = q + p["bq"].astype(x.dtype)
-        if cfg.qk_norm:
-            q = rmsnorm(q, p["q_norm"])
+        with jax.named_scope("attn.qkv"):
+            q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+            if cfg.qkv_bias:
+                q = q + p["bq"].astype(x.dtype)
+            if cfg.qk_norm:
+                q = rmsnorm(q, p["q_norm"])
     else:
-        q, k, v = _project_qkv(cfg, p, x)
-        if cfg.rope_theta > 0:
-            q = apply_rope(q, posb, cfg.rope_theta)
-            k = apply_rope(k, posb, cfg.rope_theta)
-        if per_lane:
-            # per-lane write slots: scatter row b at (b, pos[b]);
-            # lanes whose slot is out of bounds are dropped
-            lanes = jnp.arange(b)
-            cache_k = cache_k.at[lanes, posv].set(
-                k[:, 0].astype(cache_k.dtype), mode="drop")
-            cache_v = cache_v.at[lanes, posv].set(
-                v[:, 0].astype(cache_v.dtype), mode="drop")
-        else:
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
+        with jax.named_scope("attn.qkv"):
+            q, k, v = _project_qkv(cfg, p, x)
+            if cfg.rope_theta > 0:
+                q = apply_rope(q, posb, cfg.rope_theta)
+                k = apply_rope(k, posb, cfg.rope_theta)
+        with jax.named_scope("attn.kv_write"):
+            if per_lane:
+                # per-lane write slots: scatter row b at (b, pos[b]);
+                # lanes whose slot is out of bounds are dropped
+                lanes = jnp.arange(b)
+                cache_k = cache_k.at[lanes, posv].set(
+                    k[:, 0].astype(cache_k.dtype), mode="drop")
+                cache_v = cache_v.at[lanes, posv].set(
+                    v[:, 0].astype(cache_v.dtype), mode="drop")
+            else:
+                cache_k = jax.lax.dynamic_update_slice(
+                    cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
+                cache_v = jax.lax.dynamic_update_slice(
+                    cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
     smax = cache_k.shape[1]
     if offsets is None:
         kpos = jnp.broadcast_to(jnp.arange(smax, dtype=jnp.int32),
                                 (b, smax))
     else:
         kpos = _cache_positions(smax, offsets)
+    with jax.named_scope("attn.kv_read"):
+        rk, rv = cache_k.astype(q.dtype), cache_v.astype(q.dtype)
     # causal mask at qpos==pos also masks the garbage cache tail
-    out = _scores_to_out(cfg, q, cache_k.astype(q.dtype),
-                         cache_v.astype(q.dtype), posb, kpos,
+    out = _scores_to_out(cfg, q, rk, rv, posb, kpos,
                          causal=not cross, window=window)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, cache_k, cache_v
 
 
+@jax.named_scope("attn")
 def chunk_attention(cfg, p, x, cache_k, cache_v, slot, offsets, *,
                     window=0, lane_mask=None):
     """Batched chunked-prefill attention: C prompt tokens at once.
@@ -245,28 +262,32 @@ def chunk_attention(cfg, p, x, cache_k, cache_v, slot, offsets, *,
     b, c, _ = x.shape
     slots = jnp.int32(slot) + jnp.arange(c, dtype=jnp.int32)
     qpos = slots[None, :] - offsets.astype(jnp.int32)[:, None]   # (B,C)
-    q, k, v = _project_qkv(cfg, p, x)
-    if cfg.rope_theta > 0:
-        # pad queries have negative logical positions; clamp for rope
-        # (their K/V and outputs are masked / discarded anyway)
-        rp = jnp.maximum(qpos, 0)
-        q = apply_rope(q, rp, cfg.rope_theta)
-        k = apply_rope(k, rp, cfg.rope_theta)
-    k = k.astype(cache_k.dtype)
-    v = v.astype(cache_v.dtype)
-    if lane_mask is not None:
-        keep = lane_mask[:, None, None, None]
-        k = jnp.where(keep, k, jax.lax.dynamic_slice(
-            cache_k, (0, slot, 0, 0), k.shape))
-        v = jnp.where(keep, v, jax.lax.dynamic_slice(
-            cache_v, (0, slot, 0, 0), v.shape))
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(cfg, p, x)
+        if cfg.rope_theta > 0:
+            # pad queries have negative logical positions; clamp for
+            # rope (their K/V and outputs are masked / discarded anyway)
+            rp = jnp.maximum(qpos, 0)
+            q = apply_rope(q, rp, cfg.rope_theta)
+            k = apply_rope(k, rp, cfg.rope_theta)
+    with jax.named_scope("attn.kv_write"):
+        k = k.astype(cache_k.dtype)
+        v = v.astype(cache_v.dtype)
+        if lane_mask is not None:
+            keep = lane_mask[:, None, None, None]
+            k = jnp.where(keep, k, jax.lax.dynamic_slice(
+                cache_k, (0, slot, 0, 0), k.shape))
+            v = jnp.where(keep, v, jax.lax.dynamic_slice(
+                cache_v, (0, slot, 0, 0), v.shape))
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
     kpos = _cache_positions(cache_k.shape[1], offsets)
-    out = _scores_to_out(cfg, q, cache_k.astype(q.dtype),
-                         cache_v.astype(q.dtype), qpos, kpos,
+    with jax.named_scope("attn.kv_read"):
+        rk, rv = cache_k.astype(q.dtype), cache_v.astype(q.dtype)
+    out = _scores_to_out(cfg, q, rk, rv, qpos, kpos,
                          causal=True, window=window)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, cache_k, cache_v
 
 
@@ -303,6 +324,7 @@ def gather_pages(pool: jax.Array, block_tables: jax.Array,
     return g.reshape(b, read_pages * pool.shape[1], *pool.shape[2:])
 
 
+@jax.named_scope("attn.kv_write")
 def paged_write(pool: jax.Array, block_tables: jax.Array,
                 slots: jax.Array, values: jax.Array,
                 lane_mask: jax.Array | None = None) -> jax.Array:
@@ -334,6 +356,7 @@ def paged_write(pool: jax.Array, block_tables: jax.Array,
                                       mode="drop")
 
 
+@jax.named_scope("attn")
 def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
                            *, read_pages: int, window=0, offsets=None,
                            backend: str = "xla"):
@@ -355,10 +378,11 @@ def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
     posv = pos.astype(jnp.int32)
     posb = (posv if offsets is None
             else posv - offsets.astype(jnp.int32))[:, None]
-    q, k, v = _project_qkv(cfg, p, x)
-    if cfg.rope_theta > 0:
-        q = apply_rope(q, posb, cfg.rope_theta)
-        k = apply_rope(k, posb, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(cfg, p, x)
+        if cfg.rope_theta > 0:
+            q = apply_rope(q, posb, cfg.rope_theta)
+            k = apply_rope(k, posb, cfg.rope_theta)
     pool_k = paged_write(pool_k, block_tables, posv, k[:, 0])
     pool_v = paged_write(pool_v, block_tables, posv, v[:, 0])
     smax = read_pages * ps
@@ -369,20 +393,24 @@ def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
         kpos = _cache_positions(smax, offsets)
     if backend in ("pallas", "pallas_interp"):
         from repro.kernels import paged_attention as pk
-        out = pk.paged_decode_attn(
-            cfg, q, pool_k, pool_v, block_tables[:, :read_pages],
-            posb, kpos, window=window,
-            interpret=(backend == "pallas_interp"))
+        with jax.named_scope("attn.core"):
+            out = pk.paged_decode_attn(
+                cfg, q, pool_k, pool_v, block_tables[:, :read_pages],
+                posb, kpos, window=window,
+                interpret=(backend == "pallas_interp"))
     else:
-        gk = gather_pages(pool_k, block_tables, read_pages)
-        gv = gather_pages(pool_v, block_tables, read_pages)
-        out = _scores_to_out(cfg, q, gk.astype(q.dtype),
-                             gv.astype(q.dtype), posb, kpos,
+        with jax.named_scope("attn.kv_read"):
+            gk = gather_pages(pool_k, block_tables, read_pages)
+            gv = gather_pages(pool_v, block_tables, read_pages)
+            gk, gv = gk.astype(q.dtype), gv.astype(q.dtype)
+        out = _scores_to_out(cfg, q, gk, gv, posb, kpos,
                              causal=True, window=window)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, pool_k, pool_v
 
 
+@jax.named_scope("attn")
 def paged_chunk_attention(cfg, p, x, pool_k, pool_v, block_tables, slot,
                           offsets, *, read_pages: int, window=0,
                           lane_mask=None, q_lens=None):
@@ -413,11 +441,12 @@ def paged_chunk_attention(cfg, p, x, pool_k, pool_v, block_tables, slot,
     else:
         slots_b = slot[:, None] + steps[None, :]             # (B, C)
     qpos = slots_b - offsets.astype(jnp.int32)[:, None]      # (B, C)
-    q, k, v = _project_qkv(cfg, p, x)
-    if cfg.rope_theta > 0:
-        rp = jnp.maximum(qpos, 0)
-        q = apply_rope(q, rp, cfg.rope_theta)
-        k = apply_rope(k, rp, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(cfg, p, x)
+        if cfg.rope_theta > 0:
+            rp = jnp.maximum(qpos, 0)
+            q = apply_rope(q, rp, cfg.rope_theta)
+            k = apply_rope(k, rp, cfg.rope_theta)
     wmask = None if lane_mask is None else lane_mask
     if q_lens is not None:
         valid = steps[None, :] < q_lens.astype(jnp.int32)[:, None]
@@ -427,9 +456,12 @@ def paged_chunk_attention(cfg, p, x, pool_k, pool_v, block_tables, slot,
     pool_k = paged_write(pool_k, block_tables, slots_b, k, wmask)
     pool_v = paged_write(pool_v, block_tables, slots_b, v, wmask)
     kpos = _cache_positions(read_pages * ps, offsets)
-    gk = gather_pages(pool_k, block_tables, read_pages)
-    gv = gather_pages(pool_v, block_tables, read_pages)
-    out = _scores_to_out(cfg, q, gk.astype(q.dtype), gv.astype(q.dtype),
-                         qpos, kpos, causal=True, window=window)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    with jax.named_scope("attn.kv_read"):
+        gk = gather_pages(pool_k, block_tables, read_pages)
+        gv = gather_pages(pool_v, block_tables, read_pages)
+        gk, gv = gk.astype(q.dtype), gv.astype(q.dtype)
+    out = _scores_to_out(cfg, q, gk, gv, qpos, kpos, causal=True,
+                         window=window)
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, pool_k, pool_v

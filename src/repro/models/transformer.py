@@ -9,6 +9,9 @@ independent of depth). gemma2's local/global alternating pattern scans
 Decode uses per-layer KV caches stacked on the layer axis; caches shard
 their sequence dim over the ``model`` axis so a 1.6 TB gemma2 32k-batch
 cache fits (DESIGN.md §5).
+
+The layers run under the named scopes ``embed``, ``norm``, ``attn``
+(models/attention.py), ``mlp`` and ``lm_head`` (``obs.trace.SCOPES``).
 """
 from __future__ import annotations
 
@@ -166,6 +169,7 @@ def _moe_shardmap(cfg, p, x, masks, dist):
     return y, aux
 
 
+@jax.named_scope("mlp")
 def mlp_forward(cfg, p, x, masks, dist=None):
     if cfg.is_moe:
         if dist is not None and dist.mesh is not None \
@@ -187,15 +191,21 @@ def mlp_forward(cfg, p, x, masks, dist=None):
 
 def _block(cfg, p, x, positions, masks, *, window, dist=None):
     """One pre-norm transformer block (full attention)."""
-    h = norm(cfg.norm_kind, x, p["ln_attn_scale"], p.get("ln_attn_bias"))
+    with jax.named_scope("norm"):
+        h = norm(cfg.norm_kind, x, p["ln_attn_scale"],
+                 p.get("ln_attn_bias"))
     a, _ = attn.multihead_attention(cfg, p["attn"], h, positions,
                                     causal=True, window=window)
-    x = x + a
-    h = norm(cfg.norm_kind, x, p["ln_mlp_scale"], p.get("ln_mlp_bias"))
+    with jax.named_scope("attn"):
+        x = x + a
+    with jax.named_scope("norm"):
+        h = norm(cfg.norm_kind, x, p["ln_mlp_scale"], p.get("ln_mlp_bias"))
     m, aux = mlp_forward(cfg, p["mlp"], h, masks, dist)
-    return x + m, aux
+    with jax.named_scope("mlp"):
+        return x + m, aux
 
 
+@jax.named_scope("embed")
 def embed_inputs(cfg, params, tokens, patch_embeds=None):
     x = jnp.take(params["embed"], tokens, axis=0)
     x = x.astype(jnp.dtype(cfg.compute_dtype))
@@ -207,6 +217,7 @@ def embed_inputs(cfg, params, tokens, patch_embeds=None):
     return x
 
 
+@jax.named_scope("lm_head")
 def logits_from_hidden(cfg, params, x, dist=None):
     xf = norm(cfg.norm_kind, x, params["ln_f_scale"],
               params.get("ln_f_bias"))
@@ -298,14 +309,18 @@ def _run_stack(cfg, params, cache, x, masks, dist, attn_fn):
     where ck/cv are this layer's cache slices.
     Returns (hidden, new_cache)."""
     def one(window, p_l, m_l, x, aux, ck, cv):
-        h = norm(cfg.norm_kind, x, p_l["ln_attn_scale"],
-                 p_l.get("ln_attn_bias"))
+        with jax.named_scope("norm"):
+            h = norm(cfg.norm_kind, x, p_l["ln_attn_scale"],
+                     p_l.get("ln_attn_bias"))
         a, nk, nv = attn_fn(p_l["attn"], h, ck, cv, window)
-        x = x + a
-        h = norm(cfg.norm_kind, x, p_l["ln_mlp_scale"],
-                 p_l.get("ln_mlp_bias"))
+        with jax.named_scope("attn"):
+            x = x + a
+        with jax.named_scope("norm"):
+            h = norm(cfg.norm_kind, x, p_l["ln_mlp_scale"],
+                     p_l.get("ln_mlp_bias"))
         m, al = mlp_forward(cfg, p_l["mlp"], h, m_l, dist)
-        return x + m, aux + al, nk, nv
+        with jax.named_scope("mlp"):
+            return x + m, aux + al, nk, nv
 
     def body(carry, xs):
         x, aux = carry

@@ -5,7 +5,9 @@ render as complete ("X") events with microsecond timestamps; point
 events (``t1 == t0``) render as instants ("i"). Rows (tids) group by
 the request uid when a span carries one, so each request reads as its
 own timeline lane; engine-wide spans (slabs, mixed steps, train steps)
-land on row 0.
+land on row 0. ``offset_s`` shifts every timestamp onto another clock:
+``Tracer.chrome_trace`` passes its ``clock_offset``, which puts the
+spans on the JAX profiler's clock (Unix time).
 """
 from __future__ import annotations
 
@@ -37,9 +39,11 @@ def _args(attrs: dict) -> dict:
     return out
 
 
-def chrome_trace_events(spans: Iterable, pid: int = 0) -> list[dict]:
+def chrome_trace_events(spans: Iterable, pid: int = 0,
+                        offset_s: float = 0.0) -> list[dict]:
     """Spans (obs.trace.Span or their ``to_dict`` form) -> trace-event
-    dicts. Timestamps convert from monotonic seconds to microseconds."""
+    dicts. Timestamps convert from the tracer's seconds, plus
+    ``offset_s``, to microseconds."""
     out = []
     for s in spans:
         if isinstance(s, dict):
@@ -48,7 +52,7 @@ def chrome_trace_events(spans: Iterable, pid: int = 0) -> list[dict]:
         else:
             name, t0, t1, attrs = s.name, s.t0, s.t1, s.attrs
         ev = {"name": name, "pid": pid, "tid": _tid(attrs),
-              "ts": t0 * 1e6, "args": _args(attrs)}
+              "ts": (t0 + offset_s) * 1e6, "args": _args(attrs)}
         if t1 > t0:
             ev["ph"] = "X"
             ev["dur"] = (t1 - t0) * 1e6
@@ -59,12 +63,15 @@ def chrome_trace_events(spans: Iterable, pid: int = 0) -> list[dict]:
     return out
 
 
-def to_chrome_trace(spans: Iterable, pid: int = 0) -> dict:
-    return {"traceEvents": chrome_trace_events(spans, pid=pid),
+def to_chrome_trace(spans: Iterable, pid: int = 0,
+                    offset_s: float = 0.0) -> dict:
+    return {"traceEvents": chrome_trace_events(spans, pid=pid,
+                                               offset_s=offset_s),
             "displayTimeUnit": "ms"}
 
 
-def write_chrome_trace(path: str, spans: Iterable, pid: int = 0) -> str:
+def write_chrome_trace(path: str, spans: Iterable, pid: int = 0,
+                       offset_s: float = 0.0) -> str:
     with open(path, "w") as f:
-        json.dump(to_chrome_trace(spans, pid=pid), f)
+        json.dump(to_chrome_trace(spans, pid=pid, offset_s=offset_s), f)
     return path

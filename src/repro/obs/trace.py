@@ -24,6 +24,32 @@ spans, instead of nothing.
 never touch ``Span`` — tests/test_obs.py proves no span object is
 allocated on the hot path when tracing is off. Instrumented sites that
 would build attribute collections eagerly guard on ``tracer.enabled``.
+
+Engine phases go through ``phase(name)``: a context manager that always
+enters a ``jax.profiler.TraceAnnotation`` of that name (one native
+check when no profile is being collected), so a profiler trace names
+what the host was doing around each device op; an enabled ``Tracer``
+also records the phase as a ``Span``. ``Tracer.clock_offset`` maps the
+tracer's clock onto the profiler host plane's (the wall clock), so
+``chrome_trace()`` can be laid over a profile.
+
+``SCOPES`` is the one vocabulary of ``jax.named_scope`` names the model
+step carries into its HLO metadata (``op_name``), from which a device
+trace attributes each op to a layer:
+
+  embed          token embedding lookup
+  norm           a layer's pre-attention and pre-MLP norms
+  attn           one attention layer (the parent of the attn.* scopes)
+  attn.qkv       q/k/v projections and rope
+  attn.kv_write  writing the new K/V into the cache (``paged_write``)
+  attn.kv_read   reading cached K/V (``gather_pages``) and its dtype
+                 convert
+  attn.core      scores, softmax and p·v
+  attn.out       the output projection ``wo``
+  mlp            the (packed, block-sparse) MLP
+  lm_head        the final norm and the head matmul
+  sample         argmax, stop logic and lane-state update of a serving
+                 step
 """
 from __future__ import annotations
 
@@ -31,6 +57,12 @@ import json
 import os
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
+
+SCOPES = ("embed", "norm", "attn", "attn.qkv", "attn.kv_write",
+          "attn.kv_read", "attn.core", "attn.out", "mlp", "lm_head",
+          "sample")
 
 
 class Span:
@@ -59,24 +91,32 @@ class Span:
 
 class _SpanCtx:
     """Context manager for host-only phases (checkpoint writes,
-    supervisor recovery) where the span IS allowed to read the clock —
-    these run between device calls, never inside the hot loop."""
-    __slots__ = ("_tr", "_name", "_attrs", "_t0")
+    supervisor recovery, engine phases) where the span IS allowed to
+    read the clock — reading it adds no device sync. With ``ann`` (a
+    ``TraceAnnotation``) the span also lies inside that profiler
+    annotation."""
+    __slots__ = ("_tr", "_name", "_attrs", "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict,
+                 ann: TraceAnnotation | None = None):
         self._tr = tracer
         self._name = name
         self._attrs = attrs
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = self._tr.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = self._tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._attrs["error"] = exc_type.__name__
-        self._tr.span_at(self._name, self._t0, self._tr.clock(),
-                         **self._attrs)
+        self._tr.span_at(self._name, self._t0, t1, **self._attrs)
         return False
 
 
@@ -87,7 +127,9 @@ class Tracer:
     ~one small object per slab/step/event, not per token).
     ``postmortem_dir`` (optional) is where ``postmortem()`` writes its
     JSON dumps; without it the payloads still accumulate on
-    ``self.postmortems`` for programmatic access."""
+    ``self.postmortems`` for programmatic access. ``clock_offset``
+    (seconds) is the profiler's clock minus ``clock``, read once here:
+    the JAX profiler stamps host events with the wall clock."""
 
     enabled = True
 
@@ -96,6 +138,7 @@ class Tracer:
                  clock=time.monotonic):
         self.capacity = capacity
         self.clock = clock
+        self.clock_offset = time.time() - clock()
         self.postmortem_dir = postmortem_dir
         self.records: deque[Span] = deque(maxlen=capacity)
         self.postmortems: list[dict] = []
@@ -117,6 +160,11 @@ class Tracer:
 
     def span(self, name: str, **attrs) -> _SpanCtx:
         return _SpanCtx(self, name, attrs)
+
+    def phase(self, name: str, **attrs) -> _SpanCtx:
+        """An engine phase: a profiler annotation and a span, both
+        ``name``, over the ``with`` block."""
+        return _SpanCtx(self, name, attrs, TraceAnnotation(name))
 
     # ---------------------------------------------------- flight recorder
     def snapshot(self) -> list[dict]:
@@ -163,8 +211,10 @@ class Tracer:
 
     # ------------------------------------------------------------ export
     def chrome_trace(self) -> dict:
+        """The ring as trace events on the profiler's clock."""
         from repro.obs.export import to_chrome_trace
-        return to_chrome_trace(list(self.records))
+        return to_chrome_trace(list(self.records),
+                               offset_s=self.clock_offset)
 
 
 class _NullCtx:
@@ -199,6 +249,9 @@ class _NullTracer:
 
     def span(self, name, **attrs) -> _NullCtx:
         return _NULL_CTX
+
+    def phase(self, name, **attrs) -> TraceAnnotation:
+        return TraceAnnotation(name)
 
     def snapshot(self) -> list:
         return []

@@ -369,6 +369,12 @@ class Engine:
         # scheduler below — a fresh Tracer with an empty ring is truthy
         # today, but the guard costs nothing and documents the intent
         self.tracer = NULL_TRACER if tracer is None else tracer
+        # a device trace names each op's layer by the named scopes in
+        # its executable's op metadata (obs.trace.SCOPES): key the
+        # persistent compile cache by that metadata too, or a program
+        # compiled under other scopes (or none) comes back under these
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         self.metrics = MetricsRegistry()
         for kind, name, help in _METRICS:
             getattr(self.metrics, kind)(name, help)
@@ -658,8 +664,9 @@ class Engine:
         """Upload the host mirror as the device-side slab state — called
         lazily, only after admission/eviction edits."""
         if self._dirty:
-            self._dstate = {k: jnp.asarray(v)
-                            for k, v in self._mirror.items()}
+            with self.tracer.phase("engine.upload"):
+                self._dstate = {k: jnp.asarray(v)
+                                for k, v in self._mirror.items()}
             self._dirty = False
 
     def _page_cost(self, group: list[Request]) -> int:
@@ -1469,27 +1476,34 @@ class Engine:
         top (before any mutation — a crash leaves the engine at the
         previous step's consistent host-sync snapshot, which is what
         makes supervisor recovery possible), device-side faults at the
-        jitted call sites."""
+        jitted call sites.
+
+        The step's phases are profiler annotations and, with a tracer,
+        spans (``Tracer.phase``): ``engine.admit``, ``engine.upload``,
+        ``decode.slab.dispatch`` / ``mixed.step.dispatch``,
+        ``decode.slab.wait`` / ``mixed.step.wait`` and ``engine.fold``."""
         idx = self._step_idx
         self._step_idx += 1
         if self._faults is not None:
             self._faults.on_step(idx, self)
         finished: list[GenResult] = self._pending_results
         self._pending_results = []
-        self._sweep_finished(finished)
-        if self.enforce_deadlines:
-            self._cancel_expired(finished)
-        if self._preempted:
-            self._try_restore()    # older work first, unless outranked
-        self._admit()
-        self._sweep_finished(finished)   # e.g. max_new_tokens == 1
+        with self.tracer.phase("engine.admit"):
+            self._sweep_finished(finished)
+            if self.enforce_deadlines:
+                self._cancel_expired(finished)
+            if self._preempted:
+                self._try_restore()  # older work first, unless outranked
+            self._admit()
+            self._sweep_finished(finished)   # e.g. max_new_tokens == 1
+            if self.mixed:
+                decode_lanes = [i for i in self.active_lanes
+                                if self._mirror["live"][i]]
+                tails = [(i, self.lanes[i].req.prompt_len - pos)
+                         for i, pos in self._prefilling.items()]
+                plan = self.scheduler.plan_chunks(
+                    tails, len(decode_lanes), self.chunk)
         if self.mixed:
-            decode_lanes = [i for i in self.active_lanes
-                            if self._mirror["live"][i]]
-            tails = [(i, self.lanes[i].req.prompt_len - pos)
-                     for i, pos in self._prefilling.items()]
-            plan = self.scheduler.plan_chunks(tails, len(decode_lanes),
-                                              self.chunk)
             if plan:
                 self._run_mixed(decode_lanes, plan)
             elif decode_lanes:
@@ -1581,16 +1595,19 @@ class Engine:
             fmax = int(max(self._mirror["frontier"][i] for i in lanes))
             need = min(fmax + self.slab_k, self.max_len)
             r = _pow2_bucket(self.pool.slots_for(need), self.max_pages)
-            block, self._dstate, self.cache = self._slab(
-                params, self.cache, self._dstate, read_pages=r)
+            with self.tracer.phase("decode.slab.dispatch"):
+                block, self._dstate, self.cache = self._slab(
+                    params, self.cache, self._dstate, read_pages=r)
             n = len(lanes) * self.slab_k
             self.stats["pages_read"] += r * n
             self.stats["pages_read_dense_equiv"] += (
                 self.pool.slots_for(self.max_len) * n)
         else:
-            block, self._dstate, self.cache = self._slab(
-                params, self.cache, self._dstate)
-        block = np.asarray(jax.block_until_ready(block))
+            with self.tracer.phase("decode.slab.dispatch"):
+                block, self._dstate, self.cache = self._slab(
+                    params, self.cache, self._dstate)
+        with self.tracer.phase("decode.slab.wait"):
+            block = np.asarray(jax.block_until_ready(block))
         now = time.monotonic()
         self.stats["decode_s"] += now - t0
         self.stats["decode_slabs"] += 1
@@ -1600,7 +1617,8 @@ class Engine:
                 "decode.slab", t0, now, k=self.slab_k,
                 lanes=len(lanes),
                 uids=[self.lanes[i].req.uid for i in lanes])
-        self._replay(block, now)
+        with self.tracer.phase("engine.fold"):
+            self._replay(block, now)
 
     def _run_mixed(self, decode_lanes: list[int],
                    plan: dict[int, int]) -> None:
@@ -1675,11 +1693,13 @@ class Engine:
         else:
             poison = m["poison"]
         t0 = time.monotonic()
-        nxt, faulted, self.cache = self._mixed_fn(
-            params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(starts), jnp.asarray(q_lens),
-            jnp.asarray(m["offsets"]), jnp.asarray(m["bt"]),
-            read_pages=r, poison=jnp.asarray(poison))
+        with self.tracer.phase("engine.upload"):
+            args = [jnp.asarray(a) for a in (tokens, starts, q_lens,
+                                             m["offsets"], m["bt"])]
+            poison_j = jnp.asarray(poison)
+        with self.tracer.phase("mixed.step.dispatch"):
+            nxt, faulted, self.cache = self._mixed_fn(
+                params, self.cache, *args, read_pages=r, poison=poison_j)
         if split:
             m["poison"] = np.where(pmask, 0.0, m["poison"])
         else:
@@ -1692,70 +1712,77 @@ class Engine:
         # non-emitting chunk poisons the KV it wrote, so the NEXT
         # emitting call's check still catches that lane)
         fa = None
-        if decode_lanes or any(self._prefilling[i] + c
-                               >= self.lanes[i].req.prompt_len
-                               for i, c in plan.items()):
-            nxt = np.asarray(jax.block_until_ready(nxt))
-            fa = np.asarray(faulted)
+        synced = bool(decode_lanes) or any(
+            self._prefilling[i] + c >= self.lanes[i].req.prompt_len
+            for i, c in plan.items())
+        if synced:
+            with self.tracer.phase("mixed.step.wait"):
+                nxt = np.asarray(jax.block_until_ready(nxt))
+                fa = np.asarray(faulted)
         now = time.monotonic()
         if self.tracer.enabled:
+            # synced=False: no sync, so [t0, now) is the upload and the
+            # dispatch alone; the device work lands in a later call's
+            # wait
             self.tracer.span_at(
                 "mixed.step", t0, now, decode_lanes=len(decode_lanes),
                 prefill_lanes=len(plan),
-                prefill_tokens=sum(plan.values()),
+                prefill_tokens=sum(plan.values()), synced=synced,
                 uids=[self.lanes[i].req.uid
                       for i in set(decode_lanes) | set(plan)])
-        if self.mixed:
-            self.stats["mixed_steps"] += 1
-        if decode_lanes:
-            self.stats["mixed_s"] += now - t0
-            self.stats["decode_steps"] += 1
-        else:
-            # no decode lane rode along (none live, or the phased
-            # engine's batched tail prefill): pure prefill time
-            self.stats["prefill_s"] += now - t0
-        n_tok = len(decode_lanes) + sum(plan.values())
-        self.stats["pages_read"] += r * n_tok
-        self.stats["pages_read_dense_equiv"] += (
-            self.pool.slots_for(self.max_len) * n_tok)
-        if plan:
-            self.stats["prefill_chunks"] += 1
-            self.stats["prefill_tokens"] += sum(plan.values())
-        for i in decode_lanes:
-            if fa is not None and fa[i]:
-                # non-finite logits: freeze the lane (frontier does not
-                # advance, the garbage token is never kept) and leave
-                # the verdict for _harvest_faults to quarantine
-                m["faulted"][i] = True
-                m["live"][i] = False
-                continue
-            t = int(nxt[i])
-            self.lanes[i].generated.append(t)
-            self.lanes[i].token_times.append(now)
-            m["pending"][i] = t
-            m["frontier"][i] += 1
-            m["remaining"][i] -= 1
-            if (m["remaining"][i] <= 0 or m["frontier"][i] >= self.max_len
-                    or (self.eos_id is not None and t == self.eos_id)):
-                m["live"][i] = False     # same cut as _run_slab's
-            self.stats["generated_tokens"] += 1
-            self.stats["decode_tokens"] += 1
-        for i, c in plan.items():
-            pos = self._prefilling[i] + c
-            if pos < self.lanes[i].req.prompt_len:
-                self._prefilling[i] = pos
-                continue
-            del self._prefilling[i]      # tail landed: first token out
-            if fa is not None and fa[i]:
-                m["faulted"][i] = True
-                continue
-            first = int(nxt[i])
-            self.lanes[i].generated.append(first)
-            self.lanes[i].token_times.append(now)
-            m["pending"][i] = first
-            m["live"][i] = True
-            self.stats["generated_tokens"] += 1
-        self._dirty = True
+        with self.tracer.phase("engine.fold"):
+            if self.mixed:
+                self.stats["mixed_steps"] += 1
+            if decode_lanes:
+                self.stats["mixed_s"] += now - t0
+                self.stats["decode_steps"] += 1
+            else:
+                # no decode lane rode along (none live, or the phased
+                # engine's batched tail prefill): pure prefill time
+                self.stats["prefill_s"] += now - t0
+            n_tok = len(decode_lanes) + sum(plan.values())
+            self.stats["pages_read"] += r * n_tok
+            self.stats["pages_read_dense_equiv"] += (
+                self.pool.slots_for(self.max_len) * n_tok)
+            if plan:
+                self.stats["prefill_chunks"] += 1
+                self.stats["prefill_tokens"] += sum(plan.values())
+            for i in decode_lanes:
+                if fa is not None and fa[i]:
+                    # non-finite logits: freeze the lane (frontier does
+                    # not advance, the garbage token is never kept) and
+                    # leave the verdict for _harvest_faults to quarantine
+                    m["faulted"][i] = True
+                    m["live"][i] = False
+                    continue
+                t = int(nxt[i])
+                self.lanes[i].generated.append(t)
+                self.lanes[i].token_times.append(now)
+                m["pending"][i] = t
+                m["frontier"][i] += 1
+                m["remaining"][i] -= 1
+                if (m["remaining"][i] <= 0
+                        or m["frontier"][i] >= self.max_len
+                        or (self.eos_id is not None and t == self.eos_id)):
+                    m["live"][i] = False     # same cut as _run_slab's
+                self.stats["generated_tokens"] += 1
+                self.stats["decode_tokens"] += 1
+            for i, c in plan.items():
+                pos = self._prefilling[i] + c
+                if pos < self.lanes[i].req.prompt_len:
+                    self._prefilling[i] = pos
+                    continue
+                del self._prefilling[i]   # tail landed: first token out
+                if fa is not None and fa[i]:
+                    m["faulted"][i] = True
+                    continue
+                first = int(nxt[i])
+                self.lanes[i].generated.append(first)
+                self.lanes[i].token_times.append(now)
+                m["pending"][i] = first
+                m["live"][i] = True
+                self.stats["generated_tokens"] += 1
+            self._dirty = True
 
     def _replay(self, block: np.ndarray, now: float) -> None:
         """Fold a slab's token block into the host mirror using the

@@ -2,7 +2,8 @@
 (one token with cache). Weights arrive already PRUNED (zeros in pruned
 blocks) or PACKED (balanced BCSC — the paper's inference memory win;
 ``export.py``). Greedy sampling by default; temperature optional at the
-loop level.
+loop level. A step's argmax, stop logic and lane-state update run under
+the named scope ``sample`` (``obs.trace.SCOPES``).
 """
 from __future__ import annotations
 
@@ -70,21 +71,23 @@ def _run_slab(k_steps, max_len, eos_id, cache, state, park, step_fn):
     parity."""
     def body(carry, _):
         cache, pending, frontier, remaining, live, poison, faulted = carry
-        write_pos = jnp.where(live, frontier, park)
+        with jax.named_scope("sample"):
+            write_pos = jnp.where(live, frontier, park)
         logits, cache = step_fn(cache, pending[:, None], write_pos)
-        last = logits[:, -1] + poison[:, None]
-        poison = jnp.zeros_like(poison)
-        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        bad = live & ~jnp.isfinite(last).all(axis=-1)
-        faulted = faulted | bad
-        ok = live & ~bad
-        frontier = jnp.where(ok, frontier + 1, frontier)
-        remaining = jnp.where(ok, remaining - 1, remaining)
-        died = (remaining <= 0) | (frontier >= max_len) | bad
-        if eos_id is not None:
-            died |= nxt == eos_id
-        live = live & ~died
-        pending = jnp.where(live, nxt, pending)
+        with jax.named_scope("sample"):
+            last = logits[:, -1] + poison[:, None]
+            poison = jnp.zeros_like(poison)
+            nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            bad = live & ~jnp.isfinite(last).all(axis=-1)
+            faulted = faulted | bad
+            ok = live & ~bad
+            frontier = jnp.where(ok, frontier + 1, frontier)
+            remaining = jnp.where(ok, remaining - 1, remaining)
+            died = (remaining <= 0) | (frontier >= max_len) | bad
+            if eos_id is not None:
+                died |= nxt == eos_id
+            live = live & ~died
+            pending = jnp.where(live, nxt, pending)
         return (cache, pending, frontier, remaining, live, poison,
                 faulted), nxt
 
@@ -221,12 +224,14 @@ def make_mixed_step(cfg, dist=None):
         logits, cache = registry.paged_prefill_chunk(
             cfg, params, cache, tokens, starts, offsets, block_tables,
             read_pages=read_pages, masks=None, dist=dist, q_lens=q_lens)
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(q_lens.astype(jnp.int32) - 1,
-                                0)[:, None, None], axis=1)[:, 0]
-        last = last + poison[:, None]
-        faulted = ~jnp.isfinite(last).all(axis=-1)
-        return (jnp.argmax(last, -1).astype(jnp.int32), faulted, cache)
+        with jax.named_scope("sample"):
+            last = jnp.take_along_axis(
+                logits, jnp.maximum(q_lens.astype(jnp.int32) - 1,
+                                    0)[:, None, None], axis=1)[:, 0]
+            last = last + poison[:, None]
+            faulted = ~jnp.isfinite(last).all(axis=-1)
+            nxt = jnp.argmax(last, -1).astype(jnp.int32)
+        return nxt, faulted, cache
     return mixed_step
 
 

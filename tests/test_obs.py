@@ -4,10 +4,12 @@ objects allocated when disabled, no bit changed when enabled — serving
 AND training), Prometheus exposition round-trip, Chrome/Perfetto
 export, and the crash flight recorder's postmortem contents."""
 import json
+import time
 
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData, TraceAnnotation
 
 from conftest import tiny_cfg
 from repro.data.pipeline import SyntheticLM
@@ -223,13 +225,15 @@ def test_chrome_trace_export():
     assert len(ev) == 2
     slab, fin = ev
     assert slab["ph"] == "X" and slab["dur"] == pytest.approx(1e6)
-    assert slab["ts"] == pytest.approx(1e6)
+    # on the profiler's clock: the tracer's seconds plus its offset
+    assert slab["ts"] == pytest.approx((1.0 + tr.clock_offset) * 1e6)
     assert fin["ph"] == "i" and fin["s"] == "t"
     assert fin["tid"] == 2                # uid 1 -> row 2 (0 = engine)
     json.dumps(doc)                       # valid JSON all the way down
     # the exporter also takes already-serialized dicts (postmortems)
     again = obs_export.to_chrome_trace([s.to_dict()
-                                        for s in tr.records])
+                                        for s in tr.records],
+                                       offset_s=tr.clock_offset)
     assert again["traceEvents"] == ev
 
 
@@ -255,6 +259,52 @@ def test_disabled_tracing_allocates_no_spans(model, monkeypatch):
         eng.submit(p, 8)
     _drain(eng)
     assert calls == []
+
+
+def test_null_tracer_phase_allocates_no_span(monkeypatch):
+    """A phase with tracing off is the profiler annotation alone: no
+    ``Span`` is built."""
+    calls = []
+    orig = trace_mod.Span
+
+    class CountingSpan(orig):
+        def __init__(self, *a, **kw):
+            calls.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(trace_mod, "Span", CountingSpan)
+    ctx = NULL_TRACER.phase("engine.fold", lanes=3)
+    assert isinstance(ctx, TraceAnnotation)
+    with ctx:
+        pass
+    assert calls == []
+
+
+def test_tracer_phase_is_span_and_annotation(tmp_path):
+    """An enabled tracer's phase records a span of the annotation's
+    name, and on the profiler's clock the span lies within the
+    annotation the profiler recorded."""
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.phase("engine.fold", lanes=2):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (span,) = tr.records
+    assert span.name == "engine.fold" and span.attrs == {"lanes": 2}
+    assert span.dur >= 0.005
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    start = dict(pd.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    (ann,) = [e for p in pd.planes if p.name.startswith("/host:")
+              for ln in p.lines for e in ln.events
+              if e.name == "engine.fold"]
+    ev = tr.chrome_trace()["traceEvents"][0]
+    ann_us = (start + ann.start_ns) * 1e-3, (start + ann.end_ns) * 1e-3
+    assert ann_us[0] <= ev["ts"] + 1e3 and \
+        ev["ts"] + ev["dur"] <= ann_us[1] + 1e3      # within 1 ms
 
 
 # ----------------------------------------------- bitwise parity oracles
