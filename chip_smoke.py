@@ -259,9 +259,10 @@ def kernel_phase(cfg, packed, seed: int):
     n_pages = MAX_BATCH * MAX_LEN // PAGE_SIZE
     ks = jax.random.split(key, 3)
     q4 = jax.random.normal(ks[0], (b, kvh, g, hd), jnp.bfloat16)
-    pool_k = jax.random.normal(ks[1], (n_pages, PAGE_SIZE, kvh, hd),
+    # one layer's pool, as a stack of one
+    pool_k = jax.random.normal(ks[1], (1, n_pages, PAGE_SIZE, kvh, hd),
                                jnp.bfloat16)
-    pool_v = jax.random.normal(ks[2], (n_pages, PAGE_SIZE, kvh, hd),
+    pool_v = jax.random.normal(ks[2], (1, n_pages, PAGE_SIZE, kvh, hd),
                                jnp.bfloat16)
     bt = jnp.asarray(rng.permutation(n_pages)[:b * r].reshape(b, r),
                      jnp.int32)
@@ -271,10 +272,10 @@ def kernel_phase(cfg, packed, seed: int):
     kpos = attn._cache_positions(r * PAGE_SIZE, offsets)
     out = pk.paged_flash_decode(q4, pool_k, pool_v, bt,
                                 pk.mask_bias(posb, kpos),
-                                scale=1.0 / math.sqrt(hd))
+                                scale=1.0 / math.sqrt(hd), layer=0)
     want = precise(attn._scores_to_out, cfg, q4.reshape(b, 1, kvh * g, hd),
-                   attn.gather_pages(pool_k, bt, r),
-                   attn.gather_pages(pool_v, bt, r), posb, kpos,
+                   attn.gather_pages(pool_k, bt, r, 0),
+                   attn.gather_pages(pool_v, bt, r, 0), posb, kpos,
                    causal=True, window=0)
     results.append(("paged_flash_decode vs xla gather",
                     rel_diff(out.reshape(b, 1, kvh * g, hd), want)))

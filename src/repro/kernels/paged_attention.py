@@ -13,7 +13,8 @@ loaded", applied to the KV cache instead of the weights).
 
 grid = (lanes, pages); the page axis is ``arbitrary`` (it carries the
 running max / sum / accumulator scratch), lanes are parallel. One grid
-step DMAs one whole pool page across ALL kv heads — the K/V block
+step DMAs one whole pool page across ALL kv heads, of the layer the
+scalar-prefetched index names in the layer-stacked pool — the K/V block
 ``(1, ps, KV, hd)`` keeps the pool's last two dims whole, which is what
 the TPU's (8, 128) tiling rule accepts for any KV; cutting one head out
 (a ``(1, ps, 1, hd)`` block) is refused whenever KV > 1. Every head of
@@ -56,8 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_decode_kernel(scale, softcap, bt_ref, q_ref, k_ref, v_ref,
-                         bias_ref, o_ref, acc_ref, m_ref, l_ref):
+def _paged_decode_kernel(scale, softcap, bt_ref, layer_ref, q_ref, k_ref,
+                         v_ref, bias_ref, o_ref, acc_ref, m_ref, l_ref):
     """One (lane b, page j) grid step: fold pool page bt[b, j] into lane
     b's online softmax for every head. Per query group g the running
     state is kept per kv head: m/l (KV, 1), acc (KV, hd)."""
@@ -97,14 +98,17 @@ def _paged_decode_kernel(scale, softcap, bt_ref, q_ref, k_ref, v_ref,
 
 
 def paged_flash_decode(q4, pool_k, pool_v, block_tables, bias, *,
-                       scale: float, softcap: float = 0.0,
+                       scale: float, layer, softcap: float = 0.0,
                        interpret: bool = False) -> jax.Array:
-    """q4: (B, KV, G, hd); pool_k/v: (n_pages, ps, KV, hd);
-    block_tables: (B, R) int32 — the lanes' first R logical pages;
-    bias: (B, R*ps) f32, 0 where the slot may be attended, NEG_INF
-    where masked. Returns (B, KV, G, hd) f32."""
+    """q4: (B, KV, G, hd); pool_k/v: the (L, n_pages, ps, KV, hd) stack,
+    whose pages of layer ``layer`` (a scalar int32, prefetched beside the
+    block table) the DMAs read in place; block_tables: (B, R) int32 —
+    the lanes' first R logical pages; bias: (B, R*ps) f32, 0 where the
+    slot may be attended, NEG_INF where masked. Returns (B, KV, G, hd)
+    f32."""
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     b, kvh, g, hd = q4.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     r = block_tables.shape[1]
     assert bias.shape == (b, r * ps), (bias.shape, b, r, ps)
     # group-major q (a kv head's query rows are one (KV, hd) slab per
@@ -113,20 +117,20 @@ def paged_flash_decode(q4, pool_k, pool_v, block_tables, bias, *,
     qg = q4.transpose(0, 2, 1, 3)                    # (B, G, KV, hd)
     bias5 = bias.reshape(b, r, ps, 1, 1)
 
+    page = pl.BlockSpec((None, 1, ps, kvh, hd),
+                        lambda i, j, bt, lyr: (lyr[0], bt[i, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, r),
         in_specs=[
-            pl.BlockSpec((1, g, kvh, hd), lambda i, j, bt: (i, 0, 0, 0)),
-            pl.BlockSpec((1, ps, kvh, hd),
-                         lambda i, j, bt: (bt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, kvh, hd),
-                         lambda i, j, bt: (bt[i, j], 0, 0, 0)),
+            pl.BlockSpec((1, g, kvh, hd),
+                         lambda i, j, bt, lyr: (i, 0, 0, 0)),
+            page, page,
             pl.BlockSpec((1, 1, ps, 1, 1),
-                         lambda i, j, bt: (i, j, 0, 0, 0)),
+                         lambda i, j, bt, lyr: (i, j, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, kvh, hd),
-                               lambda i, j, bt: (i, 0, 0, 0)),
+                               lambda i, j, bt, lyr: (i, 0, 0, 0)),
         scratch_shapes=[pltpu.VMEM((g, kvh, hd), jnp.float32),
                         pltpu.VMEM((g, kvh, 1), jnp.float32),
                         pltpu.VMEM((g, kvh, 1), jnp.float32)],
@@ -140,7 +144,7 @@ def paged_flash_decode(q4, pool_k, pool_v, block_tables, bias, *,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(block_tables, qg, pool_k, pool_v, bias5)
+    )(block_tables, layer, qg, pool_k, pool_v, bias5)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -155,17 +159,18 @@ def mask_bias(posb, kpos, window: int = 0) -> jax.Array:
 
 
 def paged_decode_attn(cfg, q, pool_k, pool_v, block_tables, posb, kpos,
-                      *, window: int = 0,
+                      *, layer, window: int = 0,
                       interpret: bool = False) -> jax.Array:
     """models/attention.py adapter: q (B,1,H,hd) -> out (B,1,H,hd),
     matching ``_scores_to_out``'s grouped layout and mixed precision."""
     b, _, h, hd = q.shape
-    kvh = pool_k.shape[2]
+    kvh = pool_k.shape[3]
     g = h // kvh
     scale = cfg.attn_scale or 1.0 / math.sqrt(hd)
     q4 = q.reshape(b, kvh, g, hd)
     bias = mask_bias(posb, kpos, window)
     out = paged_flash_decode(
         q4, pool_k, pool_v, block_tables, bias, scale=scale,
-        softcap=float(cfg.attn_logit_softcap or 0.0), interpret=interpret)
+        softcap=float(cfg.attn_logit_softcap or 0.0), layer=layer,
+        interpret=interpret)
     return out.reshape(b, 1, h, hd).astype(q.dtype)

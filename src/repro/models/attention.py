@@ -13,6 +13,13 @@ parts under ``attn.qkv``, ``attn.kv_write``, ``attn.kv_read``,
 ``attn.core`` and ``attn.out`` (``obs.trace.SCOPES``): a device trace
 attributes each op to its part by the ``op_name`` these leave in the
 HLO metadata. Scopes change no computation.
+
+The cache-facing functions take the WHOLE layer-stacked cache that the
+layer scan carries (models/transformer.py ``_run_stack``) and ``layer``,
+the layer's index (traced): the new K/V is written at ``[layer, ...]``
+and the read indexes ``[layer]``, so a serving step updates the cache in
+place and never slices or restacks a layer's cache. A caller holding one
+layer's cache passes it as a stack of one (``cache[None]``, layer 0).
 """
 from __future__ import annotations
 
@@ -178,9 +185,10 @@ def _cache_positions(smax: int, offsets: jax.Array) -> jax.Array:
 
 
 @jax.named_scope("attn")
-def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, window=0,
-                     cross=False, offsets=None):
-    """One-token decode. x: (B,1,D); cache_k/v: (B,Smax,KV,hd); ``pos``
+def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, layer,
+                     window=0, cross=False, offsets=None):
+    """One-token decode. x: (B,1,D); cache_k/v: the (L,B,Smax,KV,hd)
+    stack, of which only layer ``layer`` is written and read; ``pos``
     is the CACHE SLOT of the new token — a scalar int32 (synchronized
     batch: every lane writes the same slot) or a (B,) vector (per-lane
     frontiers: lane b writes its own slot ``pos[b]``, engine slab
@@ -221,23 +229,25 @@ def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, window=0,
                 # per-lane write slots: scatter row b at (b, pos[b]);
                 # lanes whose slot is out of bounds are dropped
                 lanes = jnp.arange(b)
-                cache_k = cache_k.at[lanes, posv].set(
+                cache_k = cache_k.at[layer, lanes, posv].set(
                     k[:, 0].astype(cache_k.dtype), mode="drop")
-                cache_v = cache_v.at[lanes, posv].set(
+                cache_v = cache_v.at[layer, lanes, posv].set(
                     v[:, 0].astype(cache_v.dtype), mode="drop")
             else:
+                at = (layer, 0, pos, 0, 0)
                 cache_k = jax.lax.dynamic_update_slice(
-                    cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
+                    cache_k, k[None].astype(cache_k.dtype), at)
                 cache_v = jax.lax.dynamic_update_slice(
-                    cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
-    smax = cache_k.shape[1]
+                    cache_v, v[None].astype(cache_v.dtype), at)
+    smax = cache_k.shape[2]
     if offsets is None:
         kpos = jnp.broadcast_to(jnp.arange(smax, dtype=jnp.int32),
                                 (b, smax))
     else:
         kpos = _cache_positions(smax, offsets)
     with jax.named_scope("attn.kv_read"):
-        rk, rv = cache_k.astype(q.dtype), cache_v.astype(q.dtype)
+        rk = cache_k[layer].astype(q.dtype)
+        rv = cache_v[layer].astype(q.dtype)
     # causal mask at qpos==pos also masks the garbage cache tail
     out = _scores_to_out(cfg, q, rk, rv, posb, kpos,
                          causal=not cross, window=window)
@@ -248,10 +258,11 @@ def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, window=0,
 
 @jax.named_scope("attn")
 def chunk_attention(cfg, p, x, cache_k, cache_v, slot, offsets, *,
-                    window=0, lane_mask=None):
+                    layer, window=0, lane_mask=None):
     """Batched chunked-prefill attention: C prompt tokens at once.
 
-    x: (B,C,D); cache_k/v: (B,Smax,KV,hd). The chunk's K/V is written at
+    x: (B,C,D); cache_k/v: the (L,B,Smax,KV,hd) stack, of which only
+    layer ``layer`` is written and read. The chunk's K/V is written at
     cache slots [slot, slot+C); lane b's token at slot s has logical
     position ``s - offsets[b]`` (right-aligned ragged batch — left-pad
     slots are masked everywhere via the ``_PAD_POS`` sentinel).
@@ -271,19 +282,21 @@ def chunk_attention(cfg, p, x, cache_k, cache_v, slot, offsets, *,
             q = apply_rope(q, rp, cfg.rope_theta)
             k = apply_rope(k, rp, cfg.rope_theta)
     with jax.named_scope("attn.kv_write"):
-        k = k.astype(cache_k.dtype)
-        v = v.astype(cache_v.dtype)
+        k = k[None].astype(cache_k.dtype)
+        v = v[None].astype(cache_v.dtype)
+        at = (layer, 0, slot, 0, 0)
         if lane_mask is not None:
-            keep = lane_mask[:, None, None, None]
-            k = jnp.where(keep, k, jax.lax.dynamic_slice(
-                cache_k, (0, slot, 0, 0), k.shape))
-            v = jnp.where(keep, v, jax.lax.dynamic_slice(
-                cache_v, (0, slot, 0, 0), v.shape))
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
-    kpos = _cache_positions(cache_k.shape[1], offsets)
+            keep = lane_mask[None, :, None, None, None]
+            k = jnp.where(keep, k, jax.lax.dynamic_slice(cache_k, at,
+                                                         k.shape))
+            v = jnp.where(keep, v, jax.lax.dynamic_slice(cache_v, at,
+                                                         v.shape))
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k, at)
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v, at)
+    kpos = _cache_positions(cache_k.shape[2], offsets)
     with jax.named_scope("attn.kv_read"):
-        rk, rv = cache_k.astype(q.dtype), cache_v.astype(q.dtype)
+        rk = cache_k[layer].astype(q.dtype)
+        rv = cache_v[layer].astype(q.dtype)
     out = _scores_to_out(cfg, q, rk, rv, qpos, kpos,
                          causal=True, window=window)
     with jax.named_scope("attn.out"):
@@ -314,23 +327,26 @@ def chunk_attention(cfg, p, x, cache_k, cache_v, slot, offsets, *,
 
 
 def gather_pages(pool: jax.Array, block_tables: jax.Array,
-                 read_pages: int) -> jax.Array:
-    """(n_pages, ps, KV, hd) pool + (B, max_pages) tables ->
+                 read_pages: int, layer) -> jax.Array:
+    """(L, n_pages, ps, KV, hd) pool + (B, max_pages) tables ->
     (B, read_pages*ps, KV, hd): each lane's first ``read_pages`` logical
-    pages, in logical-slot order (the XLA fallback of the Pallas
-    blocked-gather kernel — kernels/paged_attention.py)."""
+    pages of layer ``layer``, gathered straight out of the stack, in
+    logical-slot order (the XLA fallback of the Pallas blocked-gather
+    kernel — kernels/paged_attention.py)."""
     b = block_tables.shape[0]
-    g = pool[block_tables[:, :read_pages]]    # (B, R, ps, KV, hd)
-    return g.reshape(b, read_pages * pool.shape[1], *pool.shape[2:])
+    g = pool[layer, block_tables[:, :read_pages]]    # (B, R, ps, KV, hd)
+    return g.reshape(b, read_pages * pool.shape[2], *pool.shape[3:])
 
 
 @jax.named_scope("attn.kv_write")
 def paged_write(pool: jax.Array, block_tables: jax.Array,
                 slots: jax.Array, values: jax.Array,
-                lane_mask: jax.Array | None = None) -> jax.Array:
+                lane_mask: jax.Array | None = None, *,
+                layer) -> jax.Array:
     """Scatter ``values`` at logical ``slots`` through the block tables.
 
-    pool: (n_pages, ps, KV, hd); slots: (B,) or (B, C) int32; values:
+    pool: (L, n_pages, ps, KV, hd), written at ``[layer, page, row]``
+    in place; slots: (B,) or (B, C) int32; values:
     slots.shape + (KV, hd). Slots past the table end (>= max_pages*ps —
     the engine parks finished lanes there) and lanes masked out by
     ``lane_mask`` are DROPPED, never clamped: a clamp would alias the
@@ -339,7 +355,7 @@ def paged_write(pool: jax.Array, block_tables: jax.Array,
     the mixed decode+prefill step pads every lane's query run to a
     common width — pad tokens must not scribble through the block
     table, whose rows beyond a lane's allocation point at page 0)."""
-    n_pages, ps = pool.shape[0], pool.shape[1]
+    n_pages, ps = pool.shape[1], pool.shape[2]
     max_pages = block_tables.shape[1]
     slots = slots.astype(jnp.int32)
     squeeze = slots.ndim == 1
@@ -351,17 +367,17 @@ def paged_write(pool: jax.Array, block_tables: jax.Array,
     phys = jnp.take_along_axis(block_tables,
                                jnp.minimum(page, max_pages - 1), axis=1)
     phys = jnp.where(ok, phys, jnp.int32(n_pages))       # OOB -> drop
-    vals = values[:, None] if squeeze else values
-    return pool.at[phys, s2 % ps].set(vals.astype(pool.dtype),
-                                      mode="drop")
+    vals = (values[:, None] if squeeze else values).astype(pool.dtype)
+    return pool.at[layer, phys, s2 % ps].set(vals, mode="drop")
 
 
 @jax.named_scope("attn")
 def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
-                           *, read_pages: int, window=0, offsets=None,
-                           backend: str = "xla"):
+                           *, layer, read_pages: int, window=0,
+                           offsets=None, backend: str = "xla"):
     """One-token decode over the paged pool. x: (B,1,D); pool_k/v:
-    (n_pages, ps, KV, hd) SHARED across lanes; ``block_tables``
+    (L, n_pages, ps, KV, hd) SHARED across lanes, of which layer
+    ``layer`` is written and read; ``block_tables``
     (B, max_pages) int32; ``pos`` (B,) is each lane's logical cache
     slot (parked lanes carry ``max_pages*ps`` — the write drops).
     ``read_pages`` is STATIC: attention reads each lane's first
@@ -371,10 +387,11 @@ def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
 
     ``backend``: 'xla' (gather + dense core — the oracle), 'pallas'
     (blocked-gather flash-decode kernel, kernels/paged_attention.py), or
-    'pallas_interp' (same kernel, interpret mode).
+    'pallas_interp' (same kernel, interpret mode); either reads layer
+    ``layer``'s pages straight out of the stack.
     Returns (out, new_pool_k, new_pool_v)."""
     b = x.shape[0]
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     posv = pos.astype(jnp.int32)
     posb = (posv if offsets is None
             else posv - offsets.astype(jnp.int32))[:, None]
@@ -383,8 +400,8 @@ def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
         if cfg.rope_theta > 0:
             q = apply_rope(q, posb, cfg.rope_theta)
             k = apply_rope(k, posb, cfg.rope_theta)
-    pool_k = paged_write(pool_k, block_tables, posv, k[:, 0])
-    pool_v = paged_write(pool_v, block_tables, posv, v[:, 0])
+    pool_k = paged_write(pool_k, block_tables, posv, k[:, 0], layer=layer)
+    pool_v = paged_write(pool_v, block_tables, posv, v[:, 0], layer=layer)
     smax = read_pages * ps
     if offsets is None:
         kpos = jnp.broadcast_to(jnp.arange(smax, dtype=jnp.int32),
@@ -396,12 +413,12 @@ def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
         with jax.named_scope("attn.core"):
             out = pk.paged_decode_attn(
                 cfg, q, pool_k, pool_v, block_tables[:, :read_pages],
-                posb, kpos, window=window,
+                posb, kpos, window=window, layer=layer,
                 interpret=(backend == "pallas_interp"))
     else:
         with jax.named_scope("attn.kv_read"):
-            gk = gather_pages(pool_k, block_tables, read_pages)
-            gv = gather_pages(pool_v, block_tables, read_pages)
+            gk = gather_pages(pool_k, block_tables, read_pages, layer)
+            gv = gather_pages(pool_v, block_tables, read_pages, layer)
             gk, gv = gk.astype(q.dtype), gv.astype(q.dtype)
         out = _scores_to_out(cfg, q, gk, gv, posb, kpos,
                              causal=True, window=window)
@@ -413,9 +430,10 @@ def paged_decode_attention(cfg, p, x, pool_k, pool_v, block_tables, pos,
 @jax.named_scope("attn")
 def paged_chunk_attention(cfg, p, x, pool_k, pool_v, block_tables, slot,
                           offsets, *, read_pages: int, window=0,
-                          lane_mask=None, q_lens=None):
+                          layer, lane_mask=None, q_lens=None):
     """Batched chunked-prefill attention over the paged pool: C prompt
-    tokens written at logical slots [slot, slot+C) through each lane's
+    tokens written at logical slots [slot, slot+C) of layer ``layer`` of
+    the (L, n_pages, ps, KV, hd) pools through each lane's
     block table (the engine allocates the covering pages before the
     first chunk). ``lane_mask`` shields running lanes the natural paged
     way — their writes are dropped, their pages never touched (the
@@ -433,7 +451,7 @@ def paged_chunk_attention(cfg, p, x, pool_k, pool_v, block_tables, slot,
     each lane's rows come out bitwise-identical to the phased paths.
     Returns (out (B,C,D), new_pool_k, new_pool_v)."""
     b, c, _ = x.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     slot = jnp.asarray(slot, jnp.int32)
     steps = jnp.arange(c, dtype=jnp.int32)
     if slot.ndim == 0:
@@ -453,12 +471,14 @@ def paged_chunk_attention(cfg, p, x, pool_k, pool_v, block_tables, slot,
         wmask = valid if wmask is None else (wmask[:, None] & valid
                                              if wmask.ndim == 1
                                              else wmask & valid)
-    pool_k = paged_write(pool_k, block_tables, slots_b, k, wmask)
-    pool_v = paged_write(pool_v, block_tables, slots_b, v, wmask)
+    pool_k = paged_write(pool_k, block_tables, slots_b, k, wmask,
+                         layer=layer)
+    pool_v = paged_write(pool_v, block_tables, slots_b, v, wmask,
+                         layer=layer)
     kpos = _cache_positions(read_pages * ps, offsets)
     with jax.named_scope("attn.kv_read"):
-        gk = gather_pages(pool_k, block_tables, read_pages)
-        gv = gather_pages(pool_v, block_tables, read_pages)
+        gk = gather_pages(pool_k, block_tables, read_pages, layer)
+        gv = gather_pages(pool_v, block_tables, read_pages, layer)
         gk, gv = gk.astype(q.dtype), gv.astype(q.dtype)
     out = _scores_to_out(cfg, q, gk, gv, qpos, kpos, causal=True,
                          window=window)

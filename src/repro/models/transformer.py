@@ -6,7 +6,8 @@ Layers are scanned (stacked params, single compiled body — compile time
 independent of depth). gemma2's local/global alternating pattern scans
 (local, global) PAIRS. BLaST masks ride along as stacked scan inputs.
 
-Decode uses per-layer KV caches stacked on the layer axis; caches shard
+Decode uses per-layer KV caches stacked on the layer axis, carried
+whole through the layer scan and updated in place; caches shard
 their sequence dim over the ``model`` axis so a 1.6 TB gemma2 32k-batch
 cache fits (DESIGN.md §5).
 
@@ -305,14 +306,22 @@ def _run_stack(cfg, params, cache, x, masks, dist, attn_fn):
     implementation behind contiguous/paged decode and chunked prefill
     (they differ ONLY in how attention reads/writes the cache).
 
-    ``attn_fn(p_attn, h, ck, cv, window) -> (attn_out, new_k, new_v)``
-    where ck/cv are this layer's cache slices.
-    Returns (hidden, new_cache)."""
-    def one(window, p_l, m_l, x, aux, ck, cv):
+    The whole layer-stacked cache rides in the scan CARRY beside the
+    hidden state: each layer writes its new K/V rows at ``[layer, ...]``
+    and reads its own layer out of the stack, so the cache is updated in
+    place. Scanned as ``xs``/``ys`` instead, every layer would slice its
+    whole cache out and the scan would restack it, and a caller's outer
+    loop (the decode slab) would copy the stacked result back into its
+    own carry at every step.
+
+    ``attn_fn(p_attn, h, cache_k, cache_v, layer, window) -> (attn_out,
+    new_k, new_v)`` where cache_k/v are the whole stacked arrays and
+    ``layer`` the traced layer index. Returns (hidden, new_cache)."""
+    def one(window, p_l, m_l, x, aux, ck, cv, layer):
         with jax.named_scope("norm"):
             h = norm(cfg.norm_kind, x, p_l["ln_attn_scale"],
                      p_l.get("ln_attn_bias"))
-        a, nk, nv = attn_fn(p_l["attn"], h, ck, cv, window)
+        a, ck, cv = attn_fn(p_l["attn"], h, ck, cv, layer, window)
         with jax.named_scope("attn"):
             x = x + a
         with jax.named_scope("norm"):
@@ -320,34 +329,32 @@ def _run_stack(cfg, params, cache, x, masks, dist, attn_fn):
                      p_l.get("ln_mlp_bias"))
         m, al = mlp_forward(cfg, p_l["mlp"], h, m_l, dist)
         with jax.named_scope("mlp"):
-            return x + m, aux + al, nk, nv
+            return x + m, aux + al, ck, cv
 
     def body(carry, xs):
-        x, aux = carry
+        # i: the first cache layer of this scan step, carried as a
+        # counter (``per`` layers per step)
+        x, aux, ck, cv, i = carry
         if cfg.layer_pattern == "local_global":
-            p_loc, m_loc, p_glb, m_glb, ck, cv = xs
-            x, aux, nk0, nv0 = one(cfg.sliding_window, p_loc, m_loc,
-                                   x, aux, ck[0], cv[0])
-            x, aux, nk1, nv1 = one(0, p_glb, m_glb, x, aux, ck[1], cv[1])
-            return (x, aux), (jnp.stack([nk0, nk1]),
-                              jnp.stack([nv0, nv1]))
-        p_l, m_l, ck, cv = xs
-        x, aux, nk, nv = one(cfg.sliding_window, p_l, m_l, x, aux, ck, cv)
-        return (x, aux), (nk, nv)
+            p_loc, m_loc, p_glb, m_glb = xs
+            x, aux, ck, cv = one(cfg.sliding_window, p_loc, m_loc, x, aux,
+                                 ck, cv, i)
+            x, aux, ck, cv = one(0, p_glb, m_glb, x, aux, ck, cv, i + 1)
+        else:
+            p_l, m_l = xs
+            x, aux, ck, cv = one(cfg.sliding_window, p_l, m_l, x, aux,
+                                 ck, cv, i)
+        return (x, aux, ck, cv, i + per), None
 
-    ns, per = n_stacks(cfg)
+    _, per = n_stacks(cfg)
     if cfg.layer_pattern == "local_global":
-        ck = cache["k"].reshape(ns, per, *cache["k"].shape[1:])
-        cv = cache["v"].reshape(ns, per, *cache["v"].shape[1:])
         xs = (params["layers_local"], _layer_masks(masks, "layers_local"),
-              params["layers_global"], _layer_masks(masks, "layers_global"),
-              ck, cv)
+              params["layers_global"], _layer_masks(masks, "layers_global"))
     else:
-        xs = (params["layers"], _layer_masks(masks, "layers"),
-              cache["k"], cache["v"])
-    (x, _), (nk, nv) = jax.lax.scan(body, (x, 0.0), xs)
-    return x, {"k": nk.reshape(cache["k"].shape),
-               "v": nv.reshape(cache["v"].shape)}
+        xs = (params["layers"], _layer_masks(masks, "layers"))
+    (x, _, ck, cv, _), _ = jax.lax.scan(
+        body, (x, 0.0, cache["k"], cache["v"], jnp.int32(0)), xs)
+    return x, dict(cache, k=ck, v=cv)
 
 
 def decode_step(cfg, params, cache, tokens, pos, *, masks=None, dist=None,
@@ -363,9 +370,10 @@ def decode_step(cfg, params, cache, tokens, pos, *, masks=None, dist=None,
     Returns (logits (B,1,V), new_cache)."""
     x = embed_inputs(cfg, params, tokens)
 
-    def attn_fn(p_a, h, ck, cv, window):
+    def attn_fn(p_a, h, ck, cv, layer, window):
         return attn.decode_attention(cfg, p_a, h, ck, cv, pos,
-                                     window=window, offsets=offsets)
+                                     window=window, offsets=offsets,
+                                     layer=layer)
 
     x, new_cache = _run_stack(cfg, params, cache, x, masks, dist, attn_fn)
     return logits_from_hidden(cfg, params, x), new_cache
@@ -383,11 +391,11 @@ def paged_decode_step(cfg, params, cache, tokens, pos, block_tables, *,
     Returns (logits (B,1,V), new_cache)."""
     x = embed_inputs(cfg, params, tokens)
 
-    def attn_fn(p_a, h, ck, cv, window):
+    def attn_fn(p_a, h, ck, cv, layer, window):
         return attn.paged_decode_attention(
             cfg, p_a, h, ck, cv, block_tables, pos,
             read_pages=read_pages, window=window, offsets=offsets,
-            backend=attn_backend)
+            backend=attn_backend, layer=layer)
 
     x, new_cache = _run_stack(cfg, params, cache, x, masks, dist, attn_fn)
     return logits_from_hidden(cfg, params, x), new_cache
@@ -407,9 +415,10 @@ def prefill_chunk(cfg, params, cache, tokens, slot, offsets, *,
     their frontier). Returns (logits (B,C,V) f32, new_cache)."""
     x = embed_inputs(cfg, params, tokens)
 
-    def attn_fn(p_a, h, ck, cv, window):
+    def attn_fn(p_a, h, ck, cv, layer, window):
         return attn.chunk_attention(cfg, p_a, h, ck, cv, slot, offsets,
-                                    window=window, lane_mask=lane_mask)
+                                    window=window, lane_mask=lane_mask,
+                                    layer=layer)
 
     x, new_cache = _run_stack(cfg, params, cache, x, masks, dist, attn_fn)
     return logits_from_hidden(cfg, params, x), new_cache
@@ -431,11 +440,11 @@ def paged_prefill_chunk(cfg, params, cache, tokens, slot, offsets,
     Returns (logits (B,C,V) f32, new_cache)."""
     x = embed_inputs(cfg, params, tokens)
 
-    def attn_fn(p_a, h, ck, cv, window):
+    def attn_fn(p_a, h, ck, cv, layer, window):
         return attn.paged_chunk_attention(
             cfg, p_a, h, ck, cv, block_tables, slot, offsets,
             read_pages=read_pages, window=window, lane_mask=lane_mask,
-            q_lens=q_lens)
+            q_lens=q_lens, layer=layer)
 
     x, new_cache = _run_stack(cfg, params, cache, x, masks, dist, attn_fn)
     return logits_from_hidden(cfg, params, x), new_cache

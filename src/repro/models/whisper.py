@@ -216,20 +216,22 @@ def decode_step(cfg, params, cache, tokens, pos, *, masks=None,
         p_l, m_l, ck, cv, cck, ccv = xs_
         h = norm(cfg.norm_kind, x, p_l["ln_attn_scale"],
                  p_l.get("ln_attn_bias"))
-        a, nk, nv = attn_mod.decode_attention(cfg, p_l["attn"], h, ck, cv,
-                                              pos)
+        a, nk, nv = attn_mod.decode_attention(cfg, p_l["attn"], h,
+                                              ck[None], cv[None], pos,
+                                              layer=0)
         x = x + a
         h = norm(cfg.norm_kind, x, p_l["ln_cross_scale"],
                  p_l.get("ln_cross_bias"))
-        c, _, _ = attn_mod.decode_attention(cfg, p_l["cross"], h, cck,
-                                            ccv, pos, cross=True)
+        c, _, _ = attn_mod.decode_attention(cfg, p_l["cross"], h,
+                                            cck[None], ccv[None], pos,
+                                            layer=0, cross=True)
         x = x + c
         h = norm(cfg.norm_kind, x, p_l["ln_mlp_scale"],
                  p_l.get("ln_mlp_bias"))
         m = sm.mlp2(h, p_l["mlp"]["w_in"], p_l["mlp"]["w_out"],
                     p_l["mlp"].get("b_in"), p_l["mlp"].get("b_out"),
                     act=cfg.mlp_act, masks=m_l, spec=cfg.blast)
-        return (x + m,), (nk, nv)
+        return (x + m,), (nk[0], nv[0])
 
     xs_ = (params["decoder"], dmasks, cache["k"], cache["v"],
            cache["ck"], cache["cv"])

@@ -169,9 +169,9 @@ def _shared_block(cfg, p, x, positions, masks, cache=None, pos=None):
                                             causal=True)
         new_cache = None
     else:
-        a, nk, nv = attn_mod.decode_attention(cfg, p["attn"], h,
-                                              cache[0], cache[1], pos)
-        new_cache = (nk, nv)
+        a, nk, nv = attn_mod.decode_attention(
+            cfg, p["attn"], h, cache[0][None], cache[1][None], pos, layer=0)
+        new_cache = (nk[0], nv[0])
     x = x + a
     h = norm(cfg.norm_kind, x, p["ln_mlp_scale"], p.get("ln_mlp_bias"))
     from repro.models.transformer import _layer_masks

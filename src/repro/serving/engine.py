@@ -36,6 +36,12 @@ the accelerator saturated across ragged, continuously-arriving requests:
   * **persistent device state** — pending/frontier/offsets/remaining/
     live (and block tables) live on the accelerator between slabs; the
     host re-uploads them only at admission/eviction events;
+  * **donated KV cache** — every jitted call that takes the cache and
+    returns it (slab, mixed step, prefill chunk, page copy, page
+    upload) donates it, so the device updates it in place and one pool
+    lives at a time; ``self.cache`` is rebound to the result and the
+    arrays passed in are deleted (serving/recovery.py says what a call
+    that dies after its dispatch leaves behind);
   * **right-aligned ragged prompts** — prompts admitted together are
     prefilled as one group in slots ``[0, W)`` (``W`` = longest prompt
     in the group); the left-pad ``offset = W - plen`` feeds rope/masking
@@ -475,7 +481,8 @@ class Engine:
                                                    page_size)
             if prefix_cache:
                 self.pcache = PrefixCache(self.pool)
-                self._copy_pages = jax.jit(make_copy_pages_step())
+                self._copy_pages = jax.jit(make_copy_pages_step(),
+                                           donate_argnums=(0,))
             self._mirror["bt"] = np.zeros((max_batch, self.max_pages),
                                           np.int32)
             # preemption plumbing: host store for offloaded page KV and
@@ -485,31 +492,35 @@ class Engine:
                              else HostKVStore(offload_capacity_bytes))
             self._offload.tracer = self.tracer
             self._gather = jax.jit(make_gather_pages_step())
-            self._scatter = jax.jit(make_scatter_pages_step())
+            self._scatter = jax.jit(make_scatter_pages_step(),
+                                    donate_argnums=(0,))
             # page-unit feasibility moves INTO the scheduler's submit
             # gate so slot- and page-infeasible requests both reject
             # synchronously at submit with a consistent error
             self.scheduler.feasibility = self._check_feasible
             self._prefill = jax.jit(
                 make_paged_prefill_chunk_step(cfg, dist=dist),
-                static_argnames=("read_pages",))
+                static_argnames=("read_pages",), donate_argnums=(1,))
             # one fused decode+prefill call (mixed engine steps AND the
             # phased engine's batched cross-request tail prefill)
             self._mixed_fn = jax.jit(make_mixed_step(cfg, dist=dist),
-                                     static_argnames=("read_pages",))
+                                     static_argnames=("read_pages",),
+                                     donate_argnums=(1,))
             # query-width bucket cap: smallest power of two >= chunk
             self._wcap = 1 << max(0, (self.chunk - 1).bit_length())
             self._slab = jax.jit(
                 make_paged_decode_slab_step(
                     cfg, slab_k, max_len, page_size, eos_id=eos_id,
                     dist=dist, attn_backend=attn_backend),
-                static_argnames=("read_pages",))
+                static_argnames=("read_pages",), donate_argnums=(1,))
         else:
             self.cache = registry.init_cache(cfg, max_batch, max_len)
             self._prefill = jax.jit(make_prefill_chunk_step(cfg,
-                                                            dist=dist))
+                                                            dist=dist),
+                                    donate_argnums=(1,))
             self._slab = jax.jit(make_decode_slab_step(
-                cfg, slab_k, max_len, eos_id=eos_id, dist=dist))
+                cfg, slab_k, max_len, eos_id=eos_id, dist=dist),
+                donate_argnums=(1,))
         self._dstate = None
         self._dirty = True
         self._uid = 0

@@ -5,9 +5,15 @@ jitted call is functional (``self.cache = step(...)`` only rebinds on
 success) and the host mirror folds results only after
 ``block_until_ready`` — so however an engine thread dies (injected
 crash, real exception, watchdog-condemned hang), the host-visible
-``(cache, mirror, lanes)`` triple is exactly the snapshot of the last
-COMPLETED sync. ``Supervisor.recover`` turns that snapshot back into a
-running engine:
+``(mirror, lanes)`` pair is exactly the snapshot of the last COMPLETED
+sync. The cache is that snapshot too, with one exception: the engine
+DONATES the cache to each jitted call that returns it (it is updated
+in place), so a call that dies after its dispatch has already consumed
+the arrays ``self.cache`` still names — they report ``is_deleted()``
+and no KV is left to download. Recovery treats that exactly as device
+loss. Injected crashes fire before the call, so they leave the cache
+intact. ``Supervisor.recover`` turns the snapshot back into a running
+engine:
 
   1. **salvage** — every live decode lane's KV pages (slots
      ``[0, frontier)``) are downloaded to the host offload store and
@@ -16,7 +22,8 @@ running engine:
      budget). Restore is PR 6's zero-re-prefill path: the lane resumes
      at its saved frontier, bitwise-identical to an uninterrupted run,
      with ``re_prefilled_tokens == 0``. Skipped when the fault lost the
-     device (``exc.device_lost``) — there is nothing left to download;
+     device (``exc.device_lost``) or the cache was consumed by a donated
+     call that died — there is nothing left to download;
   2. **relaunch** — lanes that could not salvage (device lost,
      mid-prefill, host store full) are re-queued AT THE HEAD as
      ``prompt + emitted`` with the remaining budget. Greedy decode is
@@ -42,6 +49,7 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
 
 from repro.models import registry
@@ -50,6 +58,12 @@ from repro.serving.faults import LaneFaultError, OffloadCapacityError
 from repro.serving.pages import PagePool
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import Request
+
+
+def cache_consumed(eng) -> bool:
+    """True when the engine's cache arrays were deleted: donated to a
+    jitted call that never returned its result."""
+    return any(a.is_deleted() for a in jax.tree_util.tree_leaves(eng.cache))
 
 
 class Supervisor:
@@ -203,7 +217,10 @@ class Supervisor:
         recovers the CURRENT snapshot)."""
         eng = self.engine
         t0 = time.monotonic()
-        device_lost = bool(getattr(exc, "device_lost", False))
+        # a donated call that died after its dispatch consumed the
+        # cache: no KV survives, exactly as if the device were lost
+        device_lost = (bool(getattr(exc, "device_lost", False))
+                       or cache_consumed(eng))
         # the flight recorder holds the last N spans BEFORE the crash:
         # freeze them first, so the rebuild below (which clears lanes)
         # cannot disturb the timeline being reported

@@ -5,7 +5,9 @@
   * sliding-window attention combined with ragged offsets — the window
     mask is AND-ed with the causal mask, so the ``_PAD_POS`` sentinel
     for left-pad slots must survive both, with and without page
-    boundaries inside the window.
+    boundaries inside the window;
+  * the layer-stacked cache — each function touches only layer
+    ``layer`` of the whole stack, bitwise as on that layer alone.
 """
 import numpy as np
 import jax
@@ -39,8 +41,9 @@ def test_chunk_attention_lane_mask_shields_cache_rows(setup):
     cv = _rand(rng, (b, smax, kv, hd))
     offsets = jnp.asarray([0, 1, 2], jnp.int32)
     mask = jnp.asarray([True, False, True])
-    _, nk, nv = attn.chunk_attention(cfg, p, x, ck, cv, 4, offsets,
-                                     lane_mask=mask)
+    _, nk, nv = attn.chunk_attention(cfg, p, x, ck[None], cv[None], 4,
+                                     offsets, layer=0, lane_mask=mask)
+    nk, nv = nk[0], nv[0]
     # masked lane 1: every cache row bitwise-preserved
     np.testing.assert_array_equal(np.asarray(nk[1]), np.asarray(ck[1]))
     np.testing.assert_array_equal(np.asarray(nv[1]), np.asarray(cv[1]))
@@ -67,7 +70,9 @@ def test_paged_chunk_lane_mask_shields_pool_pages(setup):
     offsets = jnp.asarray([0, 1], jnp.int32)
     mask = jnp.asarray([True, False])
     _, nk, _ = attn.paged_chunk_attention(
-        cfg, p, x, pk, pv, bt, 2, offsets, read_pages=2, lane_mask=mask)
+        cfg, p, x, pk[None], pv[None], bt, 2, offsets, layer=0,
+        read_pages=2, lane_mask=mask)
+    nk = nk[0]
     # lane 1 owns pages 1 and 3: untouched
     np.testing.assert_array_equal(np.asarray(nk[1]), np.asarray(pk[1]))
     np.testing.assert_array_equal(np.asarray(nk[3]), np.asarray(pk[3]))
@@ -122,10 +127,68 @@ def test_window_mask_across_page_boundary(setup):
     pool_v = jnp.concatenate([cv[0].reshape(4, ps, kv, hd),
                               cv[1].reshape(4, ps, kv, hd)])
     for window in (2, 5):
-        want, _, _ = attn.decode_attention(cfg, p, x, ck, cv, pos,
-                                           window=window, offsets=offsets)
+        want, _, _ = attn.decode_attention(cfg, p, x, ck[None], cv[None],
+                                           pos, layer=0, window=window,
+                                           offsets=offsets)
         got, _, _ = attn.paged_decode_attention(
-            cfg, p, x, pool_k, pool_v, bt, pos, read_pages=2,
-            window=window, offsets=offsets)
+            cfg, p, x, pool_k[None], pool_v[None], bt, pos, layer=0,
+            read_pages=2, window=window, offsets=offsets)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["decode", "decode_scalar_pos", "chunk",
+                                  "paged_decode", "paged_decode_pallas",
+                                  "paged_chunk"])
+def test_stacked_cache_layer_matches_single_layer(setup, kind):
+    """An attention function writes and reads layer ``layer`` of the
+    whole layer-stacked cache the layer scan carries: its output and
+    that layer's new rows are bitwise what the same call gives on that
+    layer alone (a stack of one), and every other layer is left as it
+    was."""
+    cfg, p = setup
+    rng = np.random.default_rng(5)
+    n_layers, layer, b, kv, hd = 3, 1, 2, 2, cfg.head_dim
+    offsets = jnp.asarray([0, 1], jnp.int32)
+    bt = jnp.asarray([[2, 4], [1, 3]], jnp.int32)
+    if kind.startswith("paged"):
+        shape = (n_layers, 6, 4, kv, hd)             # 6 pages of 4 slots
+    else:
+        shape = (n_layers, b, 8, kv, hd)
+    ck, cv = _rand(rng, shape), _rand(rng, shape)
+    c = 3 if kind in ("chunk", "paged_chunk") else 1
+    x = _rand(rng, (b, c, cfg.d_model))
+    pos = jnp.asarray([3, 5], jnp.int32)
+
+    def call(k, v, lyr):
+        if kind == "decode":
+            return attn.decode_attention(cfg, p, x, k, v, pos,
+                                         offsets=offsets, layer=lyr)
+        if kind == "decode_scalar_pos":
+            return attn.decode_attention(cfg, p, x, k, v, jnp.int32(4),
+                                         layer=lyr)
+        if kind == "chunk":
+            return attn.chunk_attention(
+                cfg, p, x, k, v, 2, offsets,
+                lane_mask=jnp.asarray([True, False]), layer=lyr)
+        if kind == "paged_chunk":
+            return attn.paged_chunk_attention(
+                cfg, p, x, k, v, bt, pos, offsets, read_pages=2,
+                q_lens=jnp.asarray([3, 1], jnp.int32), layer=lyr)
+        return attn.paged_decode_attention(
+            cfg, p, x, k, v, bt, pos, read_pages=2, offsets=offsets,
+            backend="pallas_interp" if kind.endswith("pallas") else "xla",
+            layer=lyr)
+
+    alone = slice(layer, layer + 1)
+    want, wk, wv = call(ck[alone], cv[alone], jnp.int32(0))
+    got, gk, gv = call(ck, cv, jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for new, old, one in ((gk, ck, wk), (gv, cv, wv)):
+        np.testing.assert_array_equal(np.asarray(new[layer]),
+                                      np.asarray(one[0]))
+        for other in (0, 2):
+            np.testing.assert_array_equal(np.asarray(new[other]),
+                                          np.asarray(old[other]))
+        assert not np.array_equal(np.asarray(new[layer]),
+                                  np.asarray(old[layer]))

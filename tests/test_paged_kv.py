@@ -205,13 +205,15 @@ def test_paged_write_drops_parked_and_masked_lanes():
     """A parked lane (slot >= max_pages*ps) and a lane_mask'ed lane must
     NOT write — a clamped index would corrupt pool page 0, which may
     belong to another lane."""
-    pool = jnp.zeros((3, 4, 1, 2), jnp.float32)
+    pool = jnp.zeros((1, 3, 4, 1, 2), jnp.float32)   # one layer
     bt = jnp.asarray([[1, 2], [0, 0]], jnp.int32)
     vals = jnp.ones((2, 1, 2), jnp.float32)
-    out = attn.paged_write(pool, bt, jnp.asarray([8, 8]), vals)  # parked
+    out = attn.paged_write(pool, bt, jnp.asarray([8, 8]), vals,
+                           layer=0)                          # parked
     assert float(jnp.abs(out).sum()) == 0.0
     out = attn.paged_write(pool, bt, jnp.asarray([0, 0]), vals,
-                           lane_mask=jnp.asarray([True, False]))
+                           lane_mask=jnp.asarray([True, False]),
+                           layer=0)[0]
     assert float(jnp.abs(out[1]).sum()) == 1.0 * 2   # lane 0 -> page 1
     assert float(jnp.abs(out[0]).sum()) == 0.0       # lane 1 dropped
 
@@ -242,9 +244,9 @@ def test_paged_flash_decode_kernel_matches_xla_gather():
     rng = np.random.default_rng(0)
     b, kvh, g, hd, ps, n_pages, r = 2, 2, 1, 16, 4, 6, 2
     q4 = jnp.asarray(rng.normal(size=(b, kvh, g, hd)), jnp.float32)
-    pool_k = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, hd)),
+    pool_k = jnp.asarray(rng.normal(size=(1, n_pages, ps, kvh, hd)),
                          jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, hd)),
+    pool_v = jnp.asarray(rng.normal(size=(1, n_pages, ps, kvh, hd)),
                          jnp.float32)
     bt = jnp.asarray([[3, 1], [0, 5]], jnp.int32)
     offsets = jnp.asarray([0, 2], jnp.int32)
@@ -254,11 +256,11 @@ def test_paged_flash_decode_kernel_matches_xla_gather():
     for window in (0, 3):
         bias = pk.mask_bias(posb, kpos, window)
         got = pk.paged_flash_decode(q4, pool_k, pool_v, bt, bias,
-                                    scale=1.0 / np.sqrt(hd),
+                                    scale=1.0 / np.sqrt(hd), layer=0,
                                     interpret=True)
         # oracle: gather + masked softmax (attention.py dense core)
-        gk = attn.gather_pages(pool_k, bt, r)
-        gv = attn.gather_pages(pool_v, bt, r)
+        gk = attn.gather_pages(pool_k, bt, r, 0)
+        gv = attn.gather_pages(pool_v, bt, r, 0)
         q = q4.reshape(b, 1, kvh * g, hd)
         want = attn._scores_to_out(cfg, q, gk, gv, posb, kpos,
                                    causal=True, window=window)
@@ -280,9 +282,9 @@ def test_kernel_tolerates_mixed_read_buckets():
     rng = np.random.default_rng(5)
     kvh, g, hd, ps, n_pages, r = 2, 1, 16, 4, 8, 4
     q4 = jnp.asarray(rng.normal(size=(2, kvh, g, hd)), jnp.float32)
-    pool_k = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, hd)),
+    pool_k = jnp.asarray(rng.normal(size=(1, n_pages, ps, kvh, hd)),
                          jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, hd)),
+    pool_v = jnp.asarray(rng.normal(size=(1, n_pages, ps, kvh, hd)),
                          jnp.float32)
     # lane 0: ONE live page (page 2); its table rows 1.. default to 0,
     # aliasing the long lane's first page. lane 1: four live pages.
@@ -293,9 +295,10 @@ def test_kernel_tolerates_mixed_read_buckets():
     kpos = attn._cache_positions(r * ps, offsets)
     bias = pk.mask_bias(posb, kpos, 0)
     got = pk.paged_flash_decode(q4, pool_k, pool_v, bt, bias,
-                                scale=1.0 / np.sqrt(hd), interpret=True)
-    gk = attn.gather_pages(pool_k, bt, r)
-    gv = attn.gather_pages(pool_v, bt, r)
+                                scale=1.0 / np.sqrt(hd), layer=0,
+                                interpret=True)
+    gk = attn.gather_pages(pool_k, bt, r, 0)
+    gv = attn.gather_pages(pool_v, bt, r, 0)
     want = attn._scores_to_out(cfg, q4.reshape(2, 1, kvh * g, hd),
                                gk, gv, posb, kpos, causal=True, window=0)
     np.testing.assert_allclose(np.asarray(got).reshape(2, 1, kvh * g, hd),
@@ -305,7 +308,7 @@ def test_kernel_tolerates_mixed_read_buckets():
     bias1 = pk.mask_bias(posb[:1], attn._cache_positions(ps, offsets[:1]),
                          0)
     solo = pk.paged_flash_decode(q4[:1], pool_k, pool_v, bt[:1, :1],
-                                 bias1, scale=1.0 / np.sqrt(hd),
+                                 bias1, scale=1.0 / np.sqrt(hd), layer=0,
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(got)[0], np.asarray(solo)[0],
                                rtol=1e-6, atol=1e-6)

@@ -21,7 +21,7 @@ from repro.serving.engine import Engine
 from repro.serving.faults import (EngineCrashError, FaultPlan,
                                   LaneFaultError, RequestCancelledError)
 from repro.serving.frontend import AsyncEngine
-from repro.serving.recovery import Supervisor
+from repro.serving.recovery import Supervisor, cache_consumed
 
 
 @pytest.fixture(scope="module")
@@ -421,3 +421,53 @@ def test_crash_without_recovery_budget_fails_streams(model):
                 await s.result()
 
     asyncio.run(drive())
+
+
+def test_call_that_dies_after_dispatch_relaunches(model):
+    """A donated call that dies after its dispatch has consumed the
+    cache the engine still names: recovery sees the deleted arrays,
+    takes the device-lost branch (no salvage, every live lane
+    relaunches) and every request still completes bitwise-identical."""
+    cfg, params = model
+    prompts = _prompts(cfg, (7, 5, 9), seed=2)
+
+    def make():
+        eng = Engine(cfg, params, max_batch=2, max_len=48, slab_k=4,
+                     page_size=4)
+        return eng, [eng.submit(p, 12) for p in prompts]
+
+    eng0, uids0 = make()
+    base = _drain(eng0)
+
+    eng, uids = make()
+    slab, calls = eng._slab, [0]
+
+    def dies_after_dispatch(*a, **k):
+        out = slab(*a, **k)
+        calls[0] += 1
+        if calls[0] == 2:
+            raise RuntimeError("stepper died after the slab's dispatch")
+        return out
+
+    eng._slab = dies_after_dispatch
+    got, summaries, steps = {}, [], 0
+    while (len(eng.scheduler) or eng.active_lanes or eng._preempted
+           or eng._pending_results):
+        try:
+            for r in eng.step():
+                got[r.uid] = r
+        except RuntimeError as e:
+            assert cache_consumed(eng)
+            summaries.append(Supervisor(eng).recover(e))
+        steps += 1
+        assert steps < 500
+    eng.finalize_stats()
+    _assert_parity(got, uids, base, uids0)
+    [s] = summaries
+    assert s["device_lost"] and s["salvaged_lanes"] == 0
+    assert s["relaunched_lanes"] >= 1
+    assert not cache_consumed(eng)
+    st = eng.stats
+    assert st["recovered_zero_reprefill"] == 0
+    assert st["re_prefilled_tokens"] > 0
+    assert _pool_consistent(eng) and eng.pool.referenced == 0

@@ -328,3 +328,22 @@ def test_reset_stats_and_observability_counters(model, mixed):
         assert not eng.stats[key], key
     assert eng.scheduler.rejections == 0
     assert eng._ttft == [] and eng._itl == []
+
+
+@pytest.mark.parametrize("kind", ["paged", "contiguous", "mixed"])
+def test_step_donates_the_cache(model, kind):
+    """Every jitted call that takes the KV cache donates it: after an
+    engine step the arrays the engine held before it are deleted (the
+    device updated them in place) and the engine holds live ones."""
+    cfg, params = model
+    eng = engine.Engine(cfg, params, max_batch=2, max_len=32,
+                        prefill_chunk=4, slab_k=2, page_size=4,
+                        paged=kind != "contiguous", mixed=kind == "mixed")
+    for p in _prompts(cfg, [5, 7], seed=4):
+        eng.submit(p, 6)
+    for _ in range(3):
+        before = jax.tree_util.tree_leaves(eng.cache)
+        eng.step()
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted()
+                       for a in jax.tree_util.tree_leaves(eng.cache))
